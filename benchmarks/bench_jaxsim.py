@@ -100,7 +100,8 @@ def _waterfill_row(quick: bool) -> None:
 
 def _ewma_row(quick: bool) -> None:
     from repro.core.c4d.baseline import AdaptiveBaseline
-    from repro.core.jaxsim.kernels import enable_x64, ewma_scan_kernel
+    from repro.common.jax_compat import enable_x64
+    from repro.core.jaxsim.kernels import ewma_scan_kernel
 
     windows, cells = (16, 4096) if quick else (64, 16384)
     rng = np.random.default_rng(0)

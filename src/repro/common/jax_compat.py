@@ -1,298 +1,44 @@
-"""Version-adaptive JAX/Pallas compatibility layer.
+"""The JAX/Pallas surface the repo calls, in one place.
 
-The training stack (models / kernels / parallel / train / launch) targets
-the explicit-sharding JAX API surface (``jax.sharding.AxisType``,
+The repo pins one jax (``jax==0.9.0``, requirements.txt / pyproject.toml)
+and targets its explicit-sharding API: ``jax.sharding.AxisType``,
 ``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``,
 ``jax.sharding.get_abstract_mesh``, top-level ``jax.shard_map`` with
-``check_vma``, ``pltpu.CompilerParams``).  Older-but-supported releases
-(0.4.35+) expose the same capabilities under earlier names
-(``with mesh:``, ``jax.experimental.shard_map.shard_map(check_rep=...)``,
-``pltpu.TPUCompilerParams``).  Everything in the repo goes through this
-module instead of feature-probing jax inline, so a version bump is a
-one-file change.
-
-Selection is feature-detected once at import into ``FEATURES``; the
-selection helpers (``_select_*``) are pure functions of a ``Features``
-record so tests can exercise both branches of every shim on a single
-installed jax (see tests/test_jax_compat.py).
-
-Supported range: ``MIN_JAX <= jax.__version__ < MAX_JAX_EXCLUSIVE``
-(also pinned in requirements.txt / pyproject.toml).  Outside the range,
-importing this module raises ``JaxCompatError`` naming the detected
-version — a clear error beats 59 AttributeErrors deep inside consumers.
-
-Documented in docs/compat.md.
+``check_vma``, ``pltpu.CompilerParams`` and the ``jax.enable_x64`` scope.
+Callers go through this module instead of reaching into jax inline, so a
+version bump is a one-file change (docs/compat.md).
 """
 from __future__ import annotations
 
-import contextlib
-import dataclasses
-import enum
-import inspect
-import os
-import re
-from typing import Any, Optional, Sequence, Tuple
+from typing import Sequence
 
 import jax
 
-# ---------------------------------------------------------------------------
-# Supported version range
-# ---------------------------------------------------------------------------
+AxisType = jax.sharding.AxisType
+set_mesh = jax.set_mesh
+get_abstract_mesh = jax.sharding.get_abstract_mesh
+shard_map = jax.shard_map
+axis_size = jax.lax.axis_size
 
-MIN_JAX: Tuple[int, ...] = (0, 4, 35)       # first release with jax.make_mesh
-MAX_JAX_EXCLUSIVE: Tuple[int, ...] = (0, 9)  # untested beyond; bump deliberately
-
-
-class JaxCompatError(RuntimeError):
-    """Raised when the installed jax is outside the supported range."""
-
-
-def parse_version(version: str) -> Tuple[int, ...]:
-    """'0.4.37', '0.5.0.dev20250101', '0.6.1rc1' -> leading numeric tuple."""
-    parts = []
-    for piece in version.split("."):
-        m = re.match(r"\d+", piece)
-        if m is None:
-            break
-        parts.append(int(m.group()))
-    if not parts:
-        raise JaxCompatError(f"cannot parse jax version {version!r}")
-    return tuple(parts)
-
-
-def check_supported(version: Optional[str] = None) -> Tuple[int, ...]:
-    """Validate ``version`` (default: installed jax) against the pin range."""
-    version = jax.__version__ if version is None else version
-    v = parse_version(version)
-    lo = ".".join(map(str, MIN_JAX))
-    hi = ".".join(map(str, MAX_JAX_EXCLUSIVE))
-    if v < MIN_JAX or v >= MAX_JAX_EXCLUSIVE:
-        raise JaxCompatError(
-            f"detected jax {version}, but repro supports >={lo},<{hi}. "
-            f"Install a jax in that range (see requirements.txt), or extend "
-            f"repro/common/jax_compat.py after re-running the tier-1 suite.")
-    return v
-
-
-# ---------------------------------------------------------------------------
-# Feature detection (pure selection logic, testable off the live module)
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass(frozen=True)
-class Features:
-    jax_version: Tuple[int, ...]
-    has_axis_type: bool            # jax.sharding.AxisType exists
-    make_mesh_axis_types: bool     # jax.make_mesh accepts axis_types=
-    has_get_abstract_mesh: bool    # jax.sharding.get_abstract_mesh exists
-    has_set_mesh: bool             # jax.set_mesh exists
-    has_top_level_shard_map: bool  # jax.shard_map exists
-    shard_map_check_kwarg: str     # "check_vma" (new) or "check_rep" (old)
-
-
-def detect_features() -> Features:
-    v = check_supported()
-    make_mesh_params = inspect.signature(jax.make_mesh).parameters
-    if hasattr(jax, "shard_map"):
-        sm_params = inspect.signature(jax.shard_map).parameters
-        check_kwarg = "check_vma" if "check_vma" in sm_params else "check_rep"
-        top_level = True
-    else:
-        check_kwarg = "check_rep"
-        top_level = False
-    # Pallas is probed lazily at shim-call time (tpu_compiler_params) so
-    # importing this module never pulls the pallas machinery in.
-    return Features(
-        jax_version=v,
-        has_axis_type=hasattr(jax.sharding, "AxisType"),
-        make_mesh_axis_types="axis_types" in make_mesh_params,
-        has_get_abstract_mesh=hasattr(jax.sharding, "get_abstract_mesh"),
-        has_set_mesh=hasattr(jax, "set_mesh"),
-        has_top_level_shard_map=top_level,
-        shard_map_check_kwarg=check_kwarg,
-    )
-
-
-FEATURES = detect_features()
-
-
-# ---------------------------------------------------------------------------
-# AxisType
-# ---------------------------------------------------------------------------
-
-class _FallbackAxisType(enum.Enum):
-    """Stands in for jax.sharding.AxisType on pre-explicit-sharding jax.
-
-    Pre-0.5 meshes have no axis-type concept — every axis behaves like
-    ``Auto`` (GSPMD decides) — so the value is accepted and dropped by
-    ``make_mesh``.
-    """
-    Auto = "auto"
-    Explicit = "explicit"
-    Manual = "manual"
-
-
-AxisType = jax.sharding.AxisType if FEATURES.has_axis_type else _FallbackAxisType
-
-
-# ---------------------------------------------------------------------------
-# Mesh construction / current-mesh context
-# ---------------------------------------------------------------------------
-
-def _select_make_mesh_kwargs(features: Features, axis_types) -> dict:
-    """Pure selection: which kwargs reach jax.make_mesh."""
-    if axis_types is not None and features.make_mesh_axis_types:
-        return {"axis_types": axis_types}
-    return {}
+tree_map = jax.tree.map
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None, axis_types=None) -> jax.sharding.Mesh:
-    """jax.make_mesh that tolerates axis_types on every supported jax."""
-    kwargs = _select_make_mesh_kwargs(FEATURES, axis_types)
-    if devices is not None:
-        kwargs["devices"] = devices
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` over ``devices`` (default: every visible device,
+    which the mesh must then cover exactly)."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=axis_types, devices=devices)
 
 
-@contextlib.contextmanager
-def set_mesh(mesh: jax.sharding.Mesh):
-    """Context manager making ``mesh`` the ambient mesh.
-
-    New jax: ``jax.set_mesh`` (feeds get_abstract_mesh / explicit sharding).
-    Old jax: the classic ``with mesh:`` resource env, which is what
-    ``with_sharding_constraint`` with bare PartitionSpecs reads.
-    """
-    if FEATURES.has_set_mesh:
-        with jax.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
-
-
-def get_abstract_mesh():
-    """The ambient mesh, or None when outside any mesh context.
-
-    Callers only rely on ``.empty`` / ``.axis_names`` / ``.shape`` — all
-    present on both AbstractMesh (new) and physical Mesh (old fallback).
-    """
-    if FEATURES.has_get_abstract_mesh:
-        return jax.sharding.get_abstract_mesh()
-    try:
-        from jax._src import mesh as _mesh_lib
-        return _mesh_lib.thread_resources.env.physical_mesh
-    except Exception:        # pragma: no cover - internal layout changed
-        return None
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-def _select_shard_map(features: Features):
-    """Pure selection: (callable, name of the replication-check kwarg)."""
-    if features.has_top_level_shard_map:
-        return jax.shard_map, features.shard_map_check_kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map, "check_rep"
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Top-level jax.shard_map signature on every supported jax.
-
-    ``check_vma`` maps to ``check_rep`` on older releases (same meaning:
-    verify per-output replication claims).
-    """
-    fn, check_kwarg = _select_shard_map(FEATURES)
-    return fn(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              **{check_kwarg: check_vma})
-
-
-def axis_size(axis_name) -> int:
-    """Size of a mapped mesh axis from inside shard_map.
-
-    jax.lax.axis_size is newer than the supported floor; ``psum(1, axis)``
-    is the classic equivalent and stays a static Python int.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-# ---------------------------------------------------------------------------
-# Pallas
-# ---------------------------------------------------------------------------
-
-def _select_pallas_params_cls(pltpu_module):
-    """Pure selection given a pallas-tpu-like module (testable with a stub)."""
-    cls = getattr(pltpu_module, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu_module, "TPUCompilerParams", None)
-    if cls is None:
-        raise JaxCompatError(
-            "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-            "TPUCompilerParams; unsupported jax/pallas build")
-    return cls
+def enable_x64():
+    """The 64-bit scope (``with enable_x64(): ...``) the C4D detection
+    kernels run under: float64 medians and z-scores, int64 keys."""
+    return jax.enable_x64(True)
 
 
 def tpu_compiler_params(**kwargs):
-    """Build the Pallas-TPU compiler-params object under either name.
-
-    Unknown kwargs are dropped (older dataclasses reject unexpected fields),
-    so callers can always pass the newest surface.
-    """
+    """``pltpu.CompilerParams`` (imported here so that importing this
+    module never pulls in the Pallas machinery)."""
     from jax.experimental.pallas import tpu as pltpu
-    cls = _select_pallas_params_cls(pltpu)
-    accepted = set(inspect.signature(cls).parameters)
-    return cls(**{k: v for k, v in kwargs.items() if k in accepted})
-
-
-def resolve_interpret(interpret: Optional[bool] = None) -> bool:
-    """Kernel interpret-mode default: explicit flag wins; then
-    ``REPRO_FORCE_INTERPRET=1`` (the debug knob — forces the interpreter
-    even on a real TPU); otherwise interpret everywhere except a TPU
-    backend, so Pallas kernels are testable on CPU without a TPU."""
-    if interpret is not None:
-        return interpret
-    if os.environ.get("REPRO_FORCE_INTERPRET", "0") == "1":
-        return True
-    try:
-        return jax.default_backend() != "tpu"
-    except Exception:        # pragma: no cover - backend init failure
-        return True
-
-
-# ---------------------------------------------------------------------------
-# Tree / dtype helpers
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "tree") and hasattr(jax.tree, "map"):
-    tree_map = jax.tree.map
-    tree_leaves = jax.tree.leaves
-    tree_flatten = jax.tree.flatten
-    tree_unflatten = jax.tree.unflatten
-else:                        # pragma: no cover - pre-0.4.25 layout
-    from jax import tree_util as _tree_util
-    tree_map = _tree_util.tree_map
-    tree_leaves = _tree_util.tree_leaves
-    tree_flatten = _tree_util.tree_flatten
-    tree_unflatten = _tree_util.tree_unflatten
-
-tree_map_with_path = jax.tree_util.tree_map_with_path
-
-
-def canonicalize_dtype(dtype) -> Any:
-    """Stable alias for jax.dtypes.canonicalize_dtype (x64-aware)."""
-    return jax.dtypes.canonicalize_dtype(dtype)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on every supported jax.
-
-    Newer jax returns the dict directly; 0.4.x returns a one-element list
-    of per-computation dicts.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    return pltpu.CompilerParams(**kwargs)

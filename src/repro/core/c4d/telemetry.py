@@ -274,7 +274,8 @@ def grouped_median(keys: np.ndarray, values: np.ndarray,
     from repro.core.jaxsim import effective_backend
     if (not return_groups
             and effective_backend(backend, elements=keys.size) == "jax"):
-        from repro.core.jaxsim.kernels import (PAD_KEY, enable_x64,
+        from repro.common.jax_compat import enable_x64
+        from repro.core.jaxsim.kernels import (PAD_KEY,
                                                grouped_median_kernel, pad_len)
         tp = pad_len(keys.size)
         pk = np.full(tp, PAD_KEY, np.int64)

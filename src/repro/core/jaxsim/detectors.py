@@ -42,13 +42,14 @@ from typing import List, Optional, Sequence
 
 import numpy as np
 
+from repro.common.jax_compat import enable_x64
 from repro.core.c4d.baseline import MEANAD_TO_SIGMA, AdaptiveBaseline
 from repro.core.c4d.detector import (COMM_HANG, COMM_SLOW_DST, COMM_SLOW_LINK,
                                      COMM_SLOW_SRC, DetectorConfig,
                                      NONCOMM_HANG, NONCOMM_SLOW, Verdict)
 from repro.core.c4d.telemetry import TelemetryArrays
 from repro.core.jaxsim.kernels import (PAD_KEY, batched_fused_window_kernel,
-                                       batched_slow_fold_kernel, enable_x64,
+                                       batched_slow_fold_kernel,
                                        fused_window_kernel, hang_kernel,
                                        pad_len, pair_median_kernel,
                                        slow_fold_kernel)

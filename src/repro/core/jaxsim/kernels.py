@@ -17,11 +17,15 @@ real pair keys) or an explicit validity mask and never contribute to a
 reduction.
 
 **float64 under ``enable_x64``.**  Callers (``detectors``/``waterfill``)
-run every kernel inside ``jax.experimental.enable_x64()`` so the medians,
-MAD scales and z-scores are bit-compatible with the NumPy references —
-verdict identity (score floats included) is pinned by
-tests/test_jaxsim.py.  The x64 flag participates in the jit cache key, so
-scoping it per call is free after the first trace.
+run every kernel inside ``jax_compat.enable_x64()`` so the medians, MAD
+scales and z-scores are float64, as in the NumPy references — verdict
+identity (score floats included) is pinned on the CPU backend by
+tests/test_jaxsim.py.  A TPU has no 64-bit units: XLA emulates float64 and
+int64 there and refuses some 64-bit ops outright (an f64 -> s64 bitcast
+does not lower), so the kernels use only ops its 64-bit rewrite accepts —
+the samples are sorted as floats, never as their bit patterns.  The x64
+flag participates in the jit cache key, so scoping it per call is free
+after the first trace.
 
 **No ``a*b + c`` on the exact path.**  XLA's CPU backend contracts
 multiply-add chains into FMAs (and ``lax.optimization_barrier`` does not
@@ -61,11 +65,6 @@ def pad_len(n: int, minimum: int = 16) -> int:
     kernels compile against."""
     m = max(int(n), minimum)
     return 1 << (m - 1).bit_length()
-
-
-def enable_x64():
-    """The x64 scope every kernel call runs under (bit-compat with NumPy)."""
-    return jax.experimental.enable_x64()
 
 
 # ---------------------------------------------------------------------------
@@ -133,10 +132,9 @@ def pair_median_kernel(keys, dvals, wvals):
 
     Built for the 100k-rank windows (millions of transports):
 
-      * values must be non-negative (+inf on padding), so their IEEE-754
-        bit patterns sort as int64 — a two-int64-key ``lax.sort`` is ~2x
-        faster than XLA's NaN-aware float comparator (``pack_pairs``
-        guarantees the precondition: delays and waits are >= 0);
+      * one two-key ``lax.sort`` per value array orders the transports by
+        (pair key, value), so each group's samples are contiguous and
+        ascending;
       * group extents come from cumulative scans over the sorted keys, not
         from segment scatters (XLA CPU scatter is serial and dominates at
         ~4M elements with ~T segments).
@@ -147,12 +145,8 @@ def pair_median_kernel(keys, dvals, wvals):
     marks one representative (the first) element per real group, in
     ascending key order — exactly the row-major cell order the dense
     reference reads."""
-    db = lax.bitcast_convert_type(dvals, jnp.int64)
-    wb = lax.bitcast_convert_type(wvals, jnp.int64)
-    k, dbs = lax.sort((keys, db), num_keys=2)
-    _, wbs = lax.sort((keys, wb), num_keys=2)
-    d = lax.bitcast_convert_type(dbs, jnp.float64)
-    w = lax.bitcast_convert_type(wbs, jnp.float64)
+    k, d = lax.sort((keys, dvals), num_keys=2)
+    _, w = lax.sort((keys, wvals), num_keys=2)
     t = keys.shape[0]
     idx = jnp.arange(t, dtype=jnp.int64)
     brk = k[1:] != k[:-1]
@@ -248,8 +242,8 @@ def fused_window_kernel(vmat, counts, gkey, gvalid,
     ranks that drops the sort floor from ~3.2 s to ~0.3 s, and XLA can
     vectorize the independent tiny rows where one monolithic sort cannot.
 
-    Exact-path rules preserved (module docstring): values are non-negative
-    so their IEEE-754 bit patterns sort as int64; the median is the same
+    Exact-path rules preserved (module docstring): rows are sorted as
+    float64 (+inf padding sorts last); the median is the same
     ``0.5 * (lo + hi)`` mean-of-middles; per-row lo/hi indices clamp with
     the same formulas the element-aligned kernel used, so every real
     group's median is bit-identical.  Hang scoring is ``hang_kernel``'s
@@ -261,8 +255,7 @@ def fused_window_kernel(vmat, counts, gkey, gvalid,
     Returns only group-/rank-sized arrays: at 100k ranks the host transfer
     shrinks from six element-aligned 4M arrays (~190 MB) to ~10 MB."""
     m_pad = vmat.shape[-1]
-    bits = lax.bitcast_convert_type(vmat, jnp.int64)
-    srt = lax.bitcast_convert_type(lax.sort(bits, dimension=-1), jnp.float64)
+    srt = lax.sort(vmat, dimension=-1)
     lo_i = jnp.maximum((counts - 1) // 2, 0)
     hi_i = jnp.minimum(counts // 2, m_pad - 1)
     lo = jnp.take_along_axis(srt, lo_i[None, :, None], axis=2)[:, :, 0]

@@ -22,7 +22,8 @@ from typing import Tuple
 
 import numpy as np
 
-from repro.core.jaxsim.kernels import enable_x64, pad_len, waterfill_kernel
+from repro.common.jax_compat import enable_x64
+from repro.core.jaxsim.kernels import pad_len, waterfill_kernel
 
 
 def waterfill_rates(pair_flow: np.ndarray, pair_link: np.ndarray,

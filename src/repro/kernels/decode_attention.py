@@ -47,7 +47,9 @@ def _decode_kernel(scalar_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref
         s = jnp.tanh(s / logit_cap) * logit_cap      # (group, bk)
     k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)[0]
     mask = k_pos <= pos
-    mask = jnp.logical_and(mask, jnp.where(window > 0, k_pos > pos - window, True))
+    # logical_or, not a select: Mosaic cannot lower a select on the SMEM
+    # scalar predicate over a bool vector
+    mask = jnp.logical_and(mask, jnp.logical_or(window <= 0, k_pos > pos - window))
     s = jnp.where(mask[None], s, NEG_INF)
     m_prev, l_prev = m_ref[...], l_ref[...]
     m_cur = jnp.max(s, axis=-1, keepdims=True)       # (group,1)
@@ -70,7 +72,7 @@ def _decode_kernel(scalar_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref
 def decode_attention_fwd(q, k_cache, v_cache, pos, *, window=None,
                          logit_cap: float = 0.0, scale: float,
                          block_k: int = DEFAULT_BLOCK_K,
-                         interpret: bool | None = None):
+                         interpret: bool = False):
     """q: (B,1,H,D); caches: (B,S,Hkv,D); pos scalar int32 -> (B,1,H,D)."""
     b, _, h, d = q.shape
     s, hkv = k_cache.shape[1], k_cache.shape[2]
@@ -104,7 +106,7 @@ def decode_attention_fwd(q, k_cache, v_cache, pos, *, window=None,
         ],
         compiler_params=jc.tpu_compiler_params(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=jc.resolve_interpret(interpret),
+        interpret=interpret,
         name="decode_attention_fwd",
     )(scalars, qt, kt, vt)
 

@@ -59,8 +59,10 @@ def _flash_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
         q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         mask = k_pos <= q_pos if causal else jnp.ones((block_q, block_k), bool)
+        # logical_or, not a select: Mosaic cannot lower a select on the
+        # SMEM scalar predicate over a bool vector
         mask = jnp.logical_and(
-            mask, jnp.where(window > 0, k_pos > q_pos - window, True))
+            mask, jnp.logical_or(window <= 0, k_pos > q_pos - window))
         s = jnp.where(mask[None], s, NEG_INF)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_cur = jnp.max(s, axis=-1)                  # (group, bq)
@@ -88,10 +90,12 @@ def _flash_kernel(win_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 def flash_attention_fwd(q, k, v, *, window=None, logit_cap: float = 0.0,
                         scale: float, block_q: int = DEFAULT_BLOCK_Q,
                         block_k: int = DEFAULT_BLOCK_K, causal: bool = True,
-                        interpret: bool | None = None):
+                        interpret: bool = False):
     """q: (B,S,H,D); k,v: (B,S,Hkv,D) -> (B,S,H,D).
 
-    S must be a multiple of the block sizes (the wrapper in ops.py pads).
+    S must be a multiple of the block sizes (ops.py picks ones that divide
+    S, or raises).  ``interpret=True`` runs the kernel body in the Pallas
+    interpreter (CPU tests).
     ``window``: None/0 = full causal; int or traced int32 scalar = sliding.
     """
     b, s, h, d = q.shape
@@ -129,7 +133,7 @@ def flash_attention_fwd(q, k, v, *, window=None, logit_cap: float = 0.0,
         ],
         compiler_params=jc.tpu_compiler_params(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=jc.resolve_interpret(interpret),
+        interpret=interpret,
         name="flash_attention_fwd",
     )(win, qt, kt, vt)
 

@@ -25,7 +25,7 @@ def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
 
 
 def rmsnorm_fwd(x, scale, eps: float = 1e-6, block_rows: int = DEFAULT_BLOCK_ROWS,
-                interpret: bool | None = None):
+                interpret: bool = False):
     """x: (..., D); scale: (D,). Rows are flattened and tiled."""
     orig_shape = x.shape
     d = x.shape[-1]
@@ -46,7 +46,7 @@ def rmsnorm_fwd(x, scale, eps: float = 1e-6, block_rows: int = DEFAULT_BLOCK_ROW
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(xf.shape, x.dtype),
         compiler_params=jc.tpu_compiler_params(dimension_semantics=("parallel",)),
-        interpret=jc.resolve_interpret(interpret),
+        interpret=interpret,
         name="rmsnorm_fwd",
     )(xf, scale)
     if pad:

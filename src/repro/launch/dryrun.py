@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common import jax_compat as jc
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import RunConfig, SHAPES, ShapeSpec, shape_applicable
 from repro.configs import ARCHS, get_config
 from repro.launch import mesh as meshmod
@@ -79,33 +80,6 @@ def full_units(run: RunConfig) -> int:
 # Lowering one cell
 # ---------------------------------------------------------------------------
 
-def opt_state_shardings(abstract_opt, specs_params, mesh):
-    """Optimizer leaves sharing the parameter's shape inherit its spec."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    def lookup(tree, path):
-        for p in path:
-            key = getattr(p, "key", getattr(p, "idx", None))
-            tree = tree[key]
-        return tree
-
-    def visit(path, leaf):
-        # path looks like ('m', <param path...>, '<state leaf>')
-        if len(path) >= 2 and getattr(path[0], "key", None) == "m":
-            try:
-                spec = lookup(specs_params, path[1:-1])
-            except (KeyError, TypeError):
-                return NamedSharding(mesh, P())
-            if not isinstance(spec, P):
-                return NamedSharding(mesh, P())
-            # same-rank leaves inherit; factored vectors replicate
-            if len(leaf.shape) == len(spec):
-                return NamedSharding(mesh, spec)
-        return NamedSharding(mesh, P())
-
-    return jax.tree_util.tree_map_with_path(visit, abstract_opt)
-
-
 def lower_cell(run: RunConfig, shape: ShapeSpec, mesh, *,
                unroll: bool = False, donate: bool = True):
     """Lower + compile one (config x shape) on ``mesh``. Returns compiled."""
@@ -139,7 +113,9 @@ def lower_cell(run: RunConfig, shape: ShapeSpec, mesh, *,
             step = make_train_step(model, run2, opt_cfg, mesh)
             abstract_opt = jax.eval_shape(
                 lambda p: adamw.init_state(opt_cfg, p), abstract_params)
-            oshard = opt_state_shardings(abstract_opt, pspecs, mesh)
+            oshard = shd.to_shardings(
+                shd.opt_state_specs(abstract_opt, abstract_params, pspecs),
+                mesh)
             jitted = jax.jit(step,
                              in_shardings=(pshard, oshard, bshard),
                              donate_argnums=(0, 1) if donate else ())
@@ -216,7 +192,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     try:
         compiled = lower_cell(run, shape, mesh)
         ma = compiled.memory_analysis()
-        ca = jc.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         hlo_text = compiled.as_text()
         coll = rl.parse_collectives(hlo_text)
         peak = int(ma.argument_size_in_bytes + ma.temp_size_in_bytes
@@ -352,6 +328,7 @@ def main():
                     help="recompute only roofline terms into existing records")
     ap.add_argument("--out", default="experiments/dryrun")
     args = ap.parse_args()
+    enable_compile_cache()
 
     archs = ARCHS if args.arch == "all" else [args.arch]
     shapes = list(SHAPES) if args.shape == "all" else [args.shape]

@@ -2,10 +2,13 @@
 
 A FUNCTION, not a module-level constant — importing this module never
 touches jax device state (the dry-run sets XLA_FLAGS before any jax init).
-Mesh construction goes through repro.common.jax_compat so the same code
-runs on every supported jax (axis_types exists only on newer releases).
+Mesh construction goes through repro.common.jax_compat.
 """
 from __future__ import annotations
+
+import math
+
+import jax
 
 from repro.common import jax_compat as jc
 
@@ -19,12 +22,13 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_local_mesh(data: int = 1, model: int = 1, pod: int = 0):
-    """Small mesh over however many devices exist (tests / smoke runs)."""
-    if pod:
-        return jc.make_mesh((pod, data, model), ("pod", "data", "model"),
-                            axis_types=(jc.AxisType.Auto,) * 3)
-    return jc.make_mesh((data, model), ("data", "model"),
-                        axis_types=(jc.AxisType.Auto,) * 2)
+    """Small mesh over the first ``pod * data * model`` local devices
+    (tests / smoke runs); the host may hold more."""
+    shape = (pod, data, model) if pod else (data, model)
+    axes = ("pod", "data", "model") if pod else ("data", "model")
+    return jc.make_mesh(shape, axes,
+                        devices=jax.devices()[:math.prod(shape)],
+                        axis_types=(jc.AxisType.Auto,) * len(axes))
 
 
 # TPU v5e hardware constants (roofline targets; this container is CPU-only)

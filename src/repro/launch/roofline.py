@@ -26,7 +26,6 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict
 
-from repro.common import jax_compat as jc
 from repro.launch import mesh as meshmod
 
 DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s32": 4, "u32": 4,
@@ -108,7 +107,7 @@ class CostTerms:
 
     @staticmethod
     def of(compiled) -> "CostTerms":
-        ca = jc.cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         return CostTerms(
             flops=float(ca.get("flops", 0.0)),
             hbm_bytes=float(ca.get("bytes accessed", 0.0)),

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common import jax_compat as jc
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import ShapeSpec
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.launch.mesh import make_local_mesh
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--data", type=int, default=1)
     ap.add_argument("--model", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     run = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_local_mesh(args.data, args.model)

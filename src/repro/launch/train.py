@@ -13,7 +13,7 @@ import argparse
 import json
 import logging
 
-
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.config import SHAPES, ShapeSpec
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.core.faults import Fault
@@ -35,6 +35,7 @@ def main():
     ap.add_argument("--data", type=int, default=1)
     ap.add_argument("--model", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO)
     run = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)

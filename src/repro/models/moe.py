@@ -153,7 +153,7 @@ def _maybe_shard(x, spec):
 
     from repro.common import jax_compat as jc
     mesh = jc.get_abstract_mesh()
-    if mesh is None or mesh.empty:
+    if mesh.empty:
         return x
     names = set(mesh.axis_names)
     ok = []

@@ -196,10 +196,22 @@ def param_specs(abstract_params, mesh: Mesh, fsdp_over_pod: bool = False,
     return jax.tree_util.tree_map_with_path(visit, abstract_params)
 
 
-def param_shardings(abstract_params, mesh: Mesh, fsdp_over_pod: bool = False):
-    specs = param_specs(abstract_params, mesh, fsdp_over_pod)
-    return jc.tree_map(lambda s: NamedSharding(mesh, s), specs,
-                        is_leaf=lambda x: isinstance(x, P))
+def opt_state_specs(abstract_opt, abstract_params, specs_params):
+    """An optimizer leaf shaped like its parameter (``m/<param>/mu``) takes
+    that parameter's spec, so FSDP shards the moments with the weights;
+    any other leaf (step counter, factored or quantised state) is
+    replicated."""
+    by_path = {
+        jax.tree_util.keystr(path): (leaf.shape, spec)
+        for (path, leaf), spec in zip(
+            jax.tree_util.tree_flatten_with_path(abstract_params)[0],
+            jax.tree.leaves(specs_params, is_leaf=lambda x: isinstance(x, P)))}
+
+    def visit(path, leaf):
+        # path is ('m', <param path...>, '<state leaf>')
+        shape, spec = by_path.get(jax.tree_util.keystr(path[1:-1]), (None, P()))
+        return spec if shape == leaf.shape else P()
+    return jax.tree_util.tree_map_with_path(visit, abstract_opt)
 
 
 # ---------------------------------------------------------------------------

@@ -31,6 +31,7 @@ import numpy as np
 
 from repro.core.c4d.master import OperatingPoint
 from repro.core.faults import sample_divergence_class, sample_error_class
+from repro.core.jaxsim import resolve_backend
 from repro.core.phases import HOURS
 from repro.scenarios.engine import run_scenario
 from repro.scenarios.report import CampaignReport
@@ -197,7 +198,16 @@ def run_campaign(spec: CampaignSpec, workers: int = 1,
     """Sample and execute every trial; aggregate into a ``CampaignReport``.
 
     ``workers > 1`` fans trials over a process pool; results are collected
-    in trial order, so the report is identical for any worker count."""
+    in trial order, so the report is identical for any worker count.  The
+    pool is for the NumPy backend only: each child of a ``jax``/``auto``
+    campaign would try to take the accelerator, which belongs to one
+    process at a time."""
+    backend = resolve_backend(spec.backend)
+    if workers > 1 and backend != "numpy":
+        raise ValueError(
+            f"workers={workers} with backend {backend!r}: the jax kernels "
+            f"hold the accelerator, which one process at a time may use; "
+            f"run jax campaigns with workers=1 or use backend='numpy'")
     specs = [sample_trial(spec, i) for i in range(spec.n_trials)]
     trials: List[dict] = []
     if workers > 1 and spec.n_trials > 1:

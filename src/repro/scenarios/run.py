@@ -53,6 +53,7 @@ import sys
 import time
 from typing import List
 
+from repro.core.jaxsim import jax_available
 from repro.scenarios import fleet, library, montecarlo, precision
 from repro.scenarios.engine import run_scenario
 
@@ -162,7 +163,8 @@ def main(argv=None) -> int:
                     help="override the campaign's simulated GPUs per trial")
     ap.add_argument("--workers", type=int, default=1,
                     help="process-pool width for campaign trials "
-                         "(report is identical for any value)")
+                         "(report is identical for any value; numpy "
+                         "backend only, since jax holds the accelerator)")
     ap.add_argument("--seed", type=int, default=None,
                     help="seed threaded through spec factories and campaign "
                          "samplers (default: each target's own default)")
@@ -190,6 +192,9 @@ def main(argv=None) -> int:
     ap.add_argument("--live-steps", type=int, default=14,
                     help="trainer steps for --live replay")
     args = ap.parse_args(argv)
+    if jax_available():
+        from repro.common.compile_cache import enable_compile_cache
+        enable_compile_cache()
 
     if args.list:
         for name in library.names():

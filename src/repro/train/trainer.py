@@ -66,6 +66,7 @@ class TrainerReport:
     restarts: int = 0
     detections: List[dict] = field(default_factory=list)
     losses: List[float] = field(default_factory=list)
+    grad_norms: List[float] = field(default_factory=list)
     downtime_steps: int = 0
 
 
@@ -80,8 +81,10 @@ class Trainer:
                  telemetry: Optional[RingJobTelemetry] = None):
         self.run = run
         self.shape = shape
+        # the default is a one-device job: name that device, so the mesh
+        # need not cover every chip of the host
         self.mesh = mesh or jc.make_mesh(
-            (1, 1), ("data", "model"),
+            (1, 1), ("data", "model"), devices=jax.devices()[:1],
             axis_types=(jc.AxisType.Auto,) * 2)
         self.model = build_model(run, use_kernel=use_kernel)
         self.opt_cfg = adamw.OptimizerConfig(
@@ -110,11 +113,20 @@ class Trainer:
         run = self.run
         with jc.set_mesh(self.mesh):
             abstract = jax.eval_shape(self.model.init, jax.random.key(run.train.seed))
-            self.param_sharding = shd.param_shardings(abstract, self.mesh)
+            pspecs = shd.param_specs(abstract, self.mesh)
+            self.param_sharding = shd.to_shardings(pspecs, self.mesh)
             init = jax.jit(self.model.init, out_shardings=self.param_sharding)
             self.params = init(jax.random.key(run.train.seed))
+            # the moments start on their parameters' shardings, which is
+            # also the layout the step returns: its second call then does
+            # not recompile
+            def init_opt(p):
+                return adamw.init_state(self.opt_cfg, p)
+            ospecs = shd.opt_state_specs(
+                jax.eval_shape(init_opt, abstract), abstract, pspecs)
             self.opt_state = jax.jit(
-                lambda p: adamw.init_state(self.opt_cfg, p))(self.params)
+                init_opt, out_shardings=shd.to_shardings(ospecs, self.mesh))(
+                    self.params)
             step_fn = make_train_step(self.model, run, self.opt_cfg, self.mesh)
             batch_abs = {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
                          for k, v in self.pipeline.batch(0).items()}
@@ -147,7 +159,11 @@ class Trainer:
             self.params = jax.tree.map(
                 lambda a, sh: jax.device_put(a, sh), tree["params"],
                 self.param_sharding)
-            self.opt_state = jax.device_put(tree["opt"])
+            # onto the live state's shardings: an optimizer state placed
+            # differently from what the step returns recompiles the step
+            self.opt_state = jax.tree.map(
+                lambda a, live: jax.device_put(a, live.sharding), tree["opt"],
+                self.opt_state)
         self.step = int(tree["step"])
         return s
 
@@ -216,6 +232,7 @@ class Trainer:
                 loss = float(metrics["loss"])
             self.monitor.stop(self.step)
             self.report.losses.append(loss)
+            self.report.grad_norms.append(float(metrics["grad_norm"]))
             self.report.steps_run += 1
             self.step += 1
             if self.step % run.train.checkpoint_every == 0:

@@ -1,101 +1,16 @@
-"""The version-adaptive compat layer: version gate + shim selection.
-
-Shim-selection helpers are pure functions of a Features record (or a
-stub module), so both the new-API and fallback branches are exercised on
-whatever single jax this container has installed.
-"""
-import dataclasses
-
+"""The jax surface the repo calls (repro.common.jax_compat) and the
+persistent compile-cache placement (repro.common.compile_cache)."""
 import jax
+import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.common import compile_cache
 from repro.common import jax_compat as jc
 
-# ---------------------------------------------------------------------------
-# version gate
-# ---------------------------------------------------------------------------
 
-
-def test_parse_version_variants():
-    assert jc.parse_version("0.4.37") == (0, 4, 37)
-    assert jc.parse_version("0.5.0.dev20250101") == (0, 5, 0)
-    assert jc.parse_version("0.6.1rc1") == (0, 6, 1)
-
-
-def test_parse_version_garbage_raises():
-    with pytest.raises(jc.JaxCompatError):
-        jc.parse_version("not-a-version")
-
-
-def test_installed_jax_is_supported():
-    v = jc.check_supported()
-    assert jc.MIN_JAX <= v < jc.MAX_JAX_EXCLUSIVE
-
-
-@pytest.mark.parametrize("bad", ["0.4.30", "0.2.0", "0.9.0", "1.0.0"])
-def test_out_of_range_raises_with_detected_version(bad):
-    with pytest.raises(jc.JaxCompatError) as exc:
-        jc.check_supported(bad)
-    msg = str(exc.value)
-    assert bad in msg, "error must name the detected version"
-    assert ".".join(map(str, jc.MIN_JAX)) in msg, "error must name the range"
-
-
-def test_features_match_installed_jax():
-    f = jc.detect_features()
-    assert f == jc.FEATURES
-    assert f.has_axis_type == hasattr(jax.sharding, "AxisType")
-    assert f.has_set_mesh == hasattr(jax, "set_mesh")
-    assert f.shard_map_check_kwarg in ("check_vma", "check_rep")
-
-
-# ---------------------------------------------------------------------------
-# shim selection (both branches, independent of the installed jax)
-# ---------------------------------------------------------------------------
-
-
-def _features(**overrides):
-    return dataclasses.replace(jc.FEATURES, **overrides)
-
-
-def test_make_mesh_kwargs_selection():
-    types = (jc.AxisType.Auto,)
-    new = _features(make_mesh_axis_types=True)
-    old = _features(make_mesh_axis_types=False)
-    assert jc._select_make_mesh_kwargs(new, types) == {"axis_types": types}
-    assert jc._select_make_mesh_kwargs(old, types) == {}
-    assert jc._select_make_mesh_kwargs(new, None) == {}
-
-
-def test_shard_map_selection():
-    fn, kwarg = jc._select_shard_map(_features(has_top_level_shard_map=False))
-    from jax.experimental.shard_map import shard_map as legacy
-    assert fn is legacy and kwarg == "check_rep"
-    if hasattr(jax, "shard_map"):
-        fn, kwarg = jc._select_shard_map(_features(has_top_level_shard_map=True))
-        assert fn is jax.shard_map
-        assert kwarg == jc.FEATURES.shard_map_check_kwarg
-
-
-def test_pallas_params_selection_prefers_new_name():
-    class Old:
-        TPUCompilerParams = dict
-    class New:
-        CompilerParams = list
-        TPUCompilerParams = dict
-    class Neither:
-        pass
-    assert jc._select_pallas_params_cls(Old) is dict
-    assert jc._select_pallas_params_cls(New) is list
-    with pytest.raises(jc.JaxCompatError):
-        jc._select_pallas_params_cls(Neither)
-
-
-def test_tpu_compiler_params_drops_unknown_kwargs():
-    params = jc.tpu_compiler_params(
-        dimension_semantics=("parallel",),
-        some_flag_from_the_future=object())
+def test_tpu_compiler_params_sets_dimension_semantics():
+    params = jc.tpu_compiler_params(dimension_semantics=("parallel",))
     assert tuple(params.dimension_semantics) == ("parallel",)
 
 
@@ -103,16 +18,17 @@ def test_axis_type_has_auto():
     assert hasattr(jc.AxisType, "Auto")
 
 
-def test_resolve_interpret(monkeypatch):
-    assert jc.resolve_interpret(True) is True
-    assert jc.resolve_interpret(False) is False
-    monkeypatch.delenv("REPRO_FORCE_INTERPRET", raising=False)
-    expected = jax.default_backend() != "tpu"
-    assert jc.resolve_interpret(None) is expected
-    # the debug knob forces the interpreter even on a TPU backend
-    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
-    assert jc.resolve_interpret(None) is True
-    assert jc.resolve_interpret(False) is False  # explicit flag still wins
+def test_enable_x64_is_a_scope():
+    with jc.enable_x64():
+        assert jnp.zeros(1).dtype == jnp.float64
+        assert jnp.arange(3).dtype == jnp.int64
+    assert jnp.zeros(1).dtype == jnp.float32
+
+
+def test_make_mesh_names_its_devices():
+    mesh = jc.make_mesh((1,), ("data",), devices=jax.devices()[:1],
+                        axis_types=(jc.AxisType.Auto,))
+    assert list(mesh.devices.flat) == jax.devices()[:1]
 
 
 # ---------------------------------------------------------------------------
@@ -125,14 +41,13 @@ def test_make_mesh_and_ambient_mesh_roundtrip():
     assert mesh.axis_names == ("data",)
     with jc.set_mesh(mesh):
         ambient = jc.get_abstract_mesh()
-        assert ambient is not None and not ambient.empty
+        assert not ambient.empty
         assert tuple(ambient.axis_names) == ("data",)
     after = jc.get_abstract_mesh()
-    assert after is None or after.empty
+    assert after.empty
 
 
 def test_shard_map_runs_on_single_device():
-    import jax.numpy as jnp
     mesh = jc.make_mesh((1,), ("data",), axis_types=(jc.AxisType.Auto,))
     fn = jc.shard_map(lambda x: x * 2, mesh=mesh,
                       in_specs=P("data"), out_specs=P("data"), check_vma=False)
@@ -141,27 +56,38 @@ def test_shard_map_runs_on_single_device():
     assert out.tolist() == [0.0, 2.0, 4.0, 6.0]
 
 
-def test_cost_analysis_dict_normalizes_both_shapes():
-    class ListStyle:   # jax 0.4.x
-        def cost_analysis(self):
-            return [{"flops": 7.0}]
-    class DictStyle:   # newer jax
-        def cost_analysis(self):
-            return {"flops": 7.0}
-    class EmptyStyle:
-        def cost_analysis(self):
-            return []
-    assert jc.cost_analysis_dict(ListStyle()) == {"flops": 7.0}
-    assert jc.cost_analysis_dict(DictStyle()) == {"flops": 7.0}
-    assert jc.cost_analysis_dict(EmptyStyle()) == {}
-
-
 def test_tree_helpers_roundtrip():
     tree = {"a": [1, 2], "b": 3}
-    doubled = jc.tree_map(lambda x: x * 2, tree)
-    assert doubled == {"a": [2, 4], "b": 6}
-    leaves, treedef = jc.tree_flatten(tree)
-    assert jc.tree_unflatten(treedef, leaves) == tree
-    assert jc.tree_leaves(tree) == [1, 2, 3]
-    want = "float64" if jax.config.jax_enable_x64 else "float32"
-    assert jc.canonicalize_dtype("float64") == want
+    assert jc.tree_map(lambda x: x * 2, tree) == {"a": [2, 4], "b": 6}
+
+
+# ---------------------------------------------------------------------------
+# compile cache
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def restore_cache_dir():
+    before = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_honours_env(monkeypatch, restore_cache_dir):
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv(compile_cache.CACHE_ENV, "/elsewhere/cache")
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    # JAX reads the variable itself; nothing is set in code
+    assert jax.config.jax_compilation_cache_dir is None
+
+
+def test_compile_cache_default_is_fixed_in_checkout(monkeypatch,
+                                                    restore_cache_dir):
+    monkeypatch.delenv(compile_cache.CACHE_ENV, raising=False)
+    first = compile_cache.enable_compile_cache()
+    assert first == compile_cache.enable_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == first
+    checkout = compile_cache.DEFAULT_CACHE_DIR.parent
+    assert (checkout / "src" / "repro" / "common"
+            / "compile_cache.py").is_file()
+    assert compile_cache.DEFAULT_CACHE_DIR.name == ".jax_cache"

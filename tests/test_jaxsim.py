@@ -334,7 +334,8 @@ def test_waterfill_matches_numpy_with_jitter_and_1024gpu_fabric():
 @requires_jax
 def test_ewma_scan_matches_adaptive_baseline():
     from repro.core.c4d.baseline import AdaptiveBaseline
-    from repro.core.jaxsim.kernels import enable_x64, ewma_scan_kernel
+    from repro.common.jax_compat import enable_x64
+    from repro.core.jaxsim.kernels import ewma_scan_kernel
     n = 6
     rng = np.random.default_rng(2)
     base = AdaptiveBaseline(n_ranks=n)

@@ -114,3 +114,11 @@ def test_ops_dispatch_cpu_fallback():
     out = ops.flash_attention(q, k, v, window=None, logit_cap=0.0, scale=0.125)
     want = ref.flash_attention(q, k, v, window=None, logit_cap=0.0, scale=0.125)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+def test_ops_refuses_unblockable_sequence():
+    """On a TPU the kernel path errors instead of quietly taking jnp."""
+    from repro.kernels import ops
+    assert ops._largest_block(384) == 128
+    with pytest.raises(ValueError, match="use_kernel=False"):
+        ops._largest_block(300)

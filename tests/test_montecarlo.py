@@ -37,6 +37,13 @@ def test_campaign_identical_across_worker_counts():
     assert json.dumps(a, sort_keys=True) == json.dumps(b, sort_keys=True)
 
 
+@pytest.mark.parametrize("backend", ["jax", "auto"])
+def test_worker_pool_refused_off_numpy(backend):
+    """Each pool worker would try to take the accelerator."""
+    with pytest.raises(ValueError, match="one process at a time"):
+        run_campaign(tiny_campaign(backend=backend), workers=2)
+
+
 def test_campaign_seed_changes_output_and_is_surfaced():
     a = run_campaign(tiny_campaign(seed=0)).to_json()
     b = run_campaign(tiny_campaign(seed=9)).to_json()

@@ -93,6 +93,9 @@ def test_restart_deterministic_on_sharded_mesh():
         with tempfile.TemporaryDirectory() as tmp:
             tr1 = Trainer(run, shape, workdir=os.path.join(tmp, "a"),
                           mesh=mesh22(), checkpoint_async=False)
+            # FSDP shards the optimizer moments from step 0 on
+            moments = jax.tree_util.tree_leaves(tr1.opt_state["m"])
+            assert any(not m.sharding.is_fully_replicated for m in moments)
             tr1.train(12)
             tr2 = Trainer(run, shape, workdir=os.path.join(tmp, "b"),
                           mesh=mesh22(), checkpoint_async=False)

@@ -140,7 +140,7 @@ def run(quick: bool = False) -> None:
 
 
 def _cache_info_row() -> None:
-    """Zero-cost debug row: jit/layout cache occupancy after the suite —
+    """Zero-cost debug row: factory/layout cache occupancy after the suite —
     the ``jaxsim.cache_info()`` helper surfaced in ``--json`` artifacts
     (a long fleet run growing these without bound was the bug the bounded
     factories fixed)."""
@@ -151,8 +151,6 @@ def _cache_info_row() -> None:
     emit("jaxsim/cache_info", 0.0, {
         "factory_maxsize": info["factory_maxsize"],
         "factory_entries": sum(s["size"] for s in info["factories"].values()),
-        "jit_entries": sum(v for v in info["jit_entries"].values()
-                           if v is not None),
         "layouts": f"{lay['entries']}/{lay['max_entries']}",
         "layout_hit_rate":
             f"{lay['hits'] / max(lay['hits'] + lay['misses'], 1):.2f}",

@@ -20,6 +20,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.common.tracing import span
 from repro.core.c4d.telemetry import (Heartbeat, TelemetryArrays,
                                       TelemetryWindow, TransportRecord,
                                       grouped_median)
@@ -128,20 +129,22 @@ def prefilter_arrays(window: TelemetryArrays, ranks_per_node: int,
     node = window.tr_src // ranks_per_node
 
     if transfer.size:
-        # per-node median / MAD, mapped back onto each record
-        _, node_med, _, idx = grouped_median(node, transfer,
-                                             return_groups=True)
-        absdev = np.abs(transfer - node_med[idx])
-        _, node_mad = grouped_median(node, absdev)
-        mad = node_mad * 1.4826 + 1e-12
-        suspect = (transfer - node_med[idx]) / mad[idx] > suspect_z
+        with span("c4d.prefilter.node_stats"):
+            # per-node median / MAD, mapped back onto each record
+            _, node_med, _, idx = grouped_median(node, transfer,
+                                                 return_groups=True)
+            absdev = np.abs(transfer - node_med[idx])
+            _, node_mad = grouped_median(node, absdev)
+            mad = node_mad * 1.4826 + 1e-12
+            suspect = (transfer - node_med[idx]) / mad[idx] > suspect_z
 
-        key = window.tr_src * n + window.tr_dst
-        uk, med_t, counts, edge_of = grouped_median(key, transfer,
-                                                    return_groups=True)
-        _, med_w = grouped_median(key, wait)
-        byte_sum = np.zeros(uk.size, np.int64)
-        np.add.at(byte_sum, edge_of, window.tr_bytes)
+        with span("c4d.prefilter.edge_medians"):
+            key = window.tr_src * n + window.tr_dst
+            uk, med_t, counts, edge_of = grouped_median(key, transfer,
+                                                        return_groups=True)
+            _, med_w = grouped_median(key, wait)
+            byte_sum = np.zeros(uk.size, np.int64)
+            np.add.at(byte_sum, edge_of, window.tr_bytes)
 
         m_src = np.r_[uk // n, window.tr_src[suspect]]
         m_dst = np.r_[uk % n, window.tr_dst[suspect]]

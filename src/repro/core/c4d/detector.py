@@ -32,6 +32,7 @@ from typing import TYPE_CHECKING, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from repro.common.tracing import count
 from repro.core.c4d.telemetry import (AnyWindow, TelemetryArrays,
                                       TelemetryWindow, delay_matrix,
                                       wait_matrix)
@@ -366,7 +367,9 @@ class C4DDetector:
             # hangs pre-empt slow analysis (job is stopped); the delay/wait
             # baselines are not advanced either — a hung window's matrices
             # carry no comm statistics worth learning from
+            count("c4d.hang_windows")
             return verdicts
+        count("c4d.fold_windows")
         d = delay_matrix(window, n_ranks)
         w = wait_matrix(window, n_ranks)
         verdicts = self.delay.analyze(d, baseline=baseline)

@@ -17,6 +17,7 @@ from __future__ import annotations
 from dataclasses import dataclass, field, fields
 from typing import Dict, List, Optional
 
+from repro.common.tracing import count, span
 from repro.core.c4d.agent import C4Agent, prefilter_arrays, reports_to_window
 from repro.core.c4d.attribution import (Attribution, AttributionConfig,
                                         Culprit, attribute_window)
@@ -197,10 +198,13 @@ class C4DMaster:
         A ``TelemetryArrays`` window takes the vectorized fleet path (all
         agents prefiltered in one pass); a scalar ``TelemetryWindow`` runs
         the per-agent reference path.  Both produce identical verdicts."""
-        merged = self._merge(window)
-        verdicts = self.detector.analyze(merged, n_ranks=self.n_ranks,
-                                         baseline=self.baseline)
-        return self._act(window, merged, verdicts)
+        with span("c4d.ingest", window_id=window.window_id):
+            count("c4d.windows")
+            merged = self._merge(window)
+            with span("c4d.detect"):
+                verdicts = self.detector.analyze(merged, n_ranks=self.n_ranks,
+                                                 baseline=self.baseline)
+            return self._act(window, merged, verdicts)
 
     def ingest_batch(self, windows: List[AnyWindow]) -> List[List[NodeAction]]:
         """Ingest several monitoring windows, batching the detector.
@@ -213,32 +217,53 @@ class C4DMaster:
         all hang-free windows share vmapped fused/fold dispatches via
         ``score_windows_batched`` instead of one dispatch per window."""
         from repro.core.jaxsim import effective_backend
-        merged = [self._merge(w) for w in windows]
-        batchable = (len(windows) > 1 and self.baseline is None
-                     and all(isinstance(m, TelemetryArrays) for m in merged)
-                     and effective_backend(self.detector.backend,
-                                           ranks=self.n_ranks) == "jax")
-        if batchable:
-            from repro.core.jaxsim.detectors import score_windows_batched
-            scored = score_windows_batched(merged, self.detector.cfg,
-                                           n_ranks=self.n_ranks)
-        else:
-            scored = [self.detector.analyze(m, n_ranks=self.n_ranks,
-                                            baseline=self.baseline)
-                      for m in merged]
-        return [self._act(w, m, v)
-                for w, m, v in zip(windows, merged, scored)]
+        if not windows:
+            return []
+        with span("c4d.ingest", window_id=windows[0].window_id,
+                  windows=len(windows)):
+            count("c4d.windows", len(windows))
+            merged = [self._merge(w) for w in windows]
+            batchable = (len(windows) > 1 and self.baseline is None
+                         and all(isinstance(m, TelemetryArrays)
+                                 for m in merged)
+                         and effective_backend(self.detector.backend,
+                                               ranks=self.n_ranks) == "jax")
+            with span("c4d.detect"):
+                if batchable:
+                    from repro.core.jaxsim.detectors import score_windows_batched
+                    scored = score_windows_batched(merged, self.detector.cfg,
+                                                   n_ranks=self.n_ranks)
+                else:
+                    scored = [self.detector.analyze(m, n_ranks=self.n_ranks,
+                                                    baseline=self.baseline)
+                              for m in merged]
+            return [self._act(w, m, v)
+                    for w, m, v in zip(windows, merged, scored)]
 
     def _merge(self, window: AnyWindow) -> AnyWindow:
-        if isinstance(window, TelemetryArrays):
-            return prefilter_arrays(window, self.ranks_per_node,
-                                    suspect_z=self.agents[0].suspect_z,
-                                    n_ranks=self.n_ranks)
-        reports = [a.collect(window) for a in self.agents]
-        return reports_to_window(reports, window)
+        with span("c4d.prefilter"):
+            if isinstance(window, TelemetryArrays):
+                merged = prefilter_arrays(window, self.ranks_per_node,
+                                          suspect_z=self.agents[0].suspect_z,
+                                          n_ranks=self.n_ranks)
+                count("c4d.transports_in", int(window.tr_src.size))
+                count("c4d.transports_kept", int(merged.tr_src.size))
+            else:
+                reports = [a.collect(window) for a in self.agents]
+                merged = reports_to_window(reports, window)
+                count("c4d.transports_in", len(window.transports))
+                count("c4d.transports_kept", len(merged.transports))
+        return merged
 
     def _act(self, window: AnyWindow, merged: AnyWindow,
              verdicts: List[Verdict]) -> List[NodeAction]:
+        with span("c4d.act"):
+            actions = self._fold_actions(window, merged, verdicts)
+        count("c4d.node_actions", len(actions))
+        return actions
+
+    def _fold_actions(self, window: AnyWindow, merged: AnyWindow,
+                      verdicts: List[Verdict]) -> List[NodeAction]:
         """Post-detection half of a cycle: divergence, offline log,
         attribution, node fold, confirmation streaks."""
         if self.divergence is not None and merged.train is not None:

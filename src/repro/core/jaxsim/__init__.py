@@ -146,8 +146,8 @@ def effective_backend(name: Optional[str] = None, *,
 
 
 def cache_info() -> dict:
-    """Debug snapshot of the jit/layout caches (surfaced in benchmark
-    ``--json`` output).  Import-safe without jax installed."""
+    """Debug snapshot of the kernel-factory and layout caches (surfaced in
+    benchmark ``--json`` output).  Import-safe without jax installed."""
     if not jax_available():
         return {"available": False}
     from repro.core.jaxsim import detectors, kernels

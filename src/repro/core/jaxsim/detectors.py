@@ -43,6 +43,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from repro.common.jax_compat import enable_x64
+from repro.common.tracing import count, counters, span
 from repro.core.c4d.baseline import MEANAD_TO_SIGMA, AdaptiveBaseline
 from repro.core.c4d.detector import (COMM_HANG, COMM_SLOW_DST, COMM_SLOW_LINK,
                                      COMM_SLOW_SRC, DetectorConfig,
@@ -110,20 +111,17 @@ class _WindowLayout:
 _LAYOUT_CACHE: List[_WindowLayout] = []
 _LAYOUT_CACHE_MAX = 8
 _LAYOUT_CACHE_MAX_ELEMENTS = 16_000_000
-_layout_hits = 0
-_layout_misses = 0
 
 
 def _layout_for(keys: np.ndarray, n: int) -> _WindowLayout:
-    global _layout_hits, _layout_misses
     for i, lay in enumerate(_LAYOUT_CACHE):
         if (lay.n == n and lay.keys.size == keys.size
                 and np.array_equal(lay.keys, keys)):
-            _layout_hits += 1
+            count("c4d.layout_hits")
             if i:
                 _LAYOUT_CACHE.insert(0, _LAYOUT_CACHE.pop(i))
             return lay
-    _layout_misses += 1
+    count("c4d.layout_misses")
     lay = _WindowLayout(keys, n)
     _LAYOUT_CACHE.insert(0, lay)
     total = 0
@@ -138,12 +136,15 @@ def _layout_for(keys: np.ndarray, n: int) -> _WindowLayout:
 
 def layout_cache_info() -> dict:
     """Occupancy/hit-rate of the host-side layout cache (part of
-    ``jaxsim.cache_info()``)."""
+    ``jaxsim.cache_info()``); the hits and misses are the process's
+    ``c4d.layout_hits`` / ``c4d.layout_misses`` counters."""
+    tally = counters()
     return {"entries": len(_LAYOUT_CACHE),
             "max_entries": _LAYOUT_CACHE_MAX,
             "elements": int(sum(2 * e.keys.size for e in _LAYOUT_CACHE)),
             "max_elements": _LAYOUT_CACHE_MAX_ELEMENTS,
-            "hits": _layout_hits, "misses": _layout_misses}
+            "hits": tally.get("c4d.layout_hits", 0),
+            "misses": tally.get("c4d.layout_misses", 0)}
 
 
 # ---------------------------------------------------------------------------
@@ -186,26 +187,28 @@ class _PackedWindow:
     def __init__(self, window: TelemetryArrays, n: int, n_pad: int,
                  baseline: Optional[AdaptiveBaseline]):
         t = int(window.tr_src.size)
-        keys = (window.tr_src * n + window.tr_dst if t
-                else np.zeros(0, np.int64))
-        lay = _layout_for(keys, n)
-        vmat = np.full((2, lay.g_pad, lay.m_pad), np.inf)
-        if t:
-            flat = vmat.reshape(2, -1)
-            transfer = window.tr_transfer()
-            flat[0, lay.scatter] = transfer / np.maximum(window.tr_bytes, 1)
-            flat[1, lay.scatter] = window.tr_wait()
-        h = int(window.hb_rank.size)
-        hp = pad_len(h)
-        self.layout = lay
-        self.vmat = vmat
-        self.hb_rank = _pad_index(window.hb_rank, hp)
-        self.hb_seq = _pad_index(window.hb_seq, hp)
-        self.hb_valid = np.zeros(hp, bool)
-        self.hb_valid[:h] = True
-        self.offsets = np.zeros(n_pad)
-        if baseline is not None and n:
-            self.offsets[:n] = baseline.deficit_offset(np.arange(n))
+        with span("c4d.layout"):
+            keys = (window.tr_src * n + window.tr_dst if t
+                    else np.zeros(0, np.int64))
+            lay = _layout_for(keys, n)
+        with span("c4d.pack"):
+            vmat = np.full((2, lay.g_pad, lay.m_pad), np.inf)
+            if t:
+                flat = vmat.reshape(2, -1)
+                transfer = window.tr_transfer()
+                flat[0, lay.scatter] = transfer / np.maximum(window.tr_bytes, 1)
+                flat[1, lay.scatter] = window.tr_wait()
+            h = int(window.hb_rank.size)
+            hp = pad_len(h)
+            self.layout = lay
+            self.vmat = vmat
+            self.hb_rank = _pad_index(window.hb_rank, hp)
+            self.hb_seq = _pad_index(window.hb_seq, hp)
+            self.hb_valid = np.zeros(hp, bool)
+            self.hb_valid[:h] = True
+            self.offsets = np.zeros(n_pad)
+            if baseline is not None and n:
+                self.offsets[:n] = baseline.deficit_offset(np.arange(n))
 
     def bucket(self):
         """Static-shape signature: windows in the same bucket vmap
@@ -320,28 +323,34 @@ def _score_single(window: TelemetryArrays, cfg: DetectorConfig, n: int,
     pw = _PackedWindow(window, n, n_pad, baseline)
     lay = pw.layout
     with enable_x64():
-        res = fused_window_kernel(
-            pw.vmat, lay.counts, lay.gkey, lay.gvalid, pw.hb_rank,
-            pw.hb_seq, pw.hb_valid, jnp.asarray(pw.offsets),
-            cfg.hang_grace, n=n, n_pad=n_pad)
-        hung = np.asarray(res["hung"])
+        with span("c4d.fused"):
+            res = fused_window_kernel(
+                pw.vmat, lay.counts, lay.gkey, lay.gvalid, pw.hb_rank,
+                pw.hb_seq, pw.hb_valid, jnp.asarray(pw.offsets),
+                cfg.hang_grace, n=n, n_pad=n_pad)
+            hung = np.asarray(res["hung"])
         if hung.any():
             # hangs pre-empt slow analysis and freeze the baseline —
             # identical to the NumPy composite
-            return _hang_verdict_list(hung, np.asarray(res["seqs"]),
-                                      float(res["med"]),
-                                      np.asarray(res["is_src"]))
-        dmed = np.asarray(res["dmed"])
-        wmed = np.asarray(res["wmed"])
-        cd, sd = _mixed_center_scale(dmed, lay.gvalid, lay.gkey, n,
-                                     baseline, "delay")
-        cw, sw = _mixed_center_scale(wmed, lay.gvalid, lay.gkey, n,
-                                     baseline, "wait")
-        fold = slow_fold_kernel(lay.gkey, lay.gvalid, dmed, wmed, cd, sd,
-                                cw, sw, cfg.mad_threshold,
-                                cfg.row_col_fraction, cfg.min_observations,
-                                n=n, n_pad=n_pad)
-        verdicts = _fold_verdict_list(fold, lay.gkey, n)
+            count("c4d.hang_windows")
+            with span("c4d.hang_verdicts"):
+                return _hang_verdict_list(hung, np.asarray(res["seqs"]),
+                                          float(res["med"]),
+                                          np.asarray(res["is_src"]))
+        count("c4d.fold_windows")
+        with span("c4d.center_scale"):
+            dmed = np.asarray(res["dmed"])
+            wmed = np.asarray(res["wmed"])
+            cd, sd = _mixed_center_scale(dmed, lay.gvalid, lay.gkey, n,
+                                         baseline, "delay")
+            cw, sw = _mixed_center_scale(wmed, lay.gvalid, lay.gkey, n,
+                                         baseline, "wait")
+        with span("c4d.fold"):
+            fold = slow_fold_kernel(lay.gkey, lay.gvalid, dmed, wmed, cd, sd,
+                                    cw, sw, cfg.mad_threshold,
+                                    cfg.row_col_fraction,
+                                    cfg.min_observations, n=n, n_pad=n_pad)
+            verdicts = _fold_verdict_list(fold, lay.gkey, n)
     if baseline is not None:
         _advance_baseline(window, cfg, n, baseline, lay.gkey, lay.gvalid,
                           dmed, wmed)
@@ -382,49 +391,56 @@ def score_windows_batched(windows: Sequence[TelemetryArrays],
     with enable_x64():
         fused_fn = batched_fused_window_kernel(n, n_pad)
         for idxs in buckets.values():
-            res = fused_fn(
-                np.stack([packs[i].vmat for i in idxs]),
-                np.stack([packs[i].layout.counts for i in idxs]),
-                np.stack([packs[i].layout.gkey for i in idxs]),
-                np.stack([packs[i].layout.gvalid for i in idxs]),
-                np.stack([packs[i].hb_rank for i in idxs]),
-                np.stack([packs[i].hb_seq for i in idxs]),
-                np.stack([packs[i].hb_valid for i in idxs]),
-                np.stack([packs[i].offsets for i in idxs]),
-                cfg.hang_grace)
-            res = {k: np.asarray(v) for k, v in res.items()}
+            with span("c4d.fused", windows=len(idxs)):
+                res = fused_fn(
+                    np.stack([packs[i].vmat for i in idxs]),
+                    np.stack([packs[i].layout.counts for i in idxs]),
+                    np.stack([packs[i].layout.gkey for i in idxs]),
+                    np.stack([packs[i].layout.gvalid for i in idxs]),
+                    np.stack([packs[i].hb_rank for i in idxs]),
+                    np.stack([packs[i].hb_seq for i in idxs]),
+                    np.stack([packs[i].hb_valid for i in idxs]),
+                    np.stack([packs[i].offsets for i in idxs]),
+                    cfg.hang_grace)
+                res = {k: np.asarray(v) for k, v in res.items()}
             for b, i in enumerate(idxs):
                 hung = res["hung"][b]
                 if hung.any():
-                    results[i] = _hang_verdict_list(
-                        hung, res["seqs"][b], float(res["med"][b]),
-                        res["is_src"][b])
+                    count("c4d.hang_windows")
+                    with span("c4d.hang_verdicts"):
+                        results[i] = _hang_verdict_list(
+                            hung, res["seqs"][b], float(res["med"][b]),
+                            res["is_src"][b])
                 else:
                     slow.setdefault(packs[i].layout.g_pad, []).append(
                         (i, res["dmed"][b], res["wmed"][b]))
 
         fold_fn = batched_slow_fold_kernel(n, n_pad)
         for entries in slow.values():
-            gkey = np.stack([packs[i].layout.gkey for i, _, _ in entries])
-            valid = np.stack([packs[i].layout.gvalid for i, _, _ in entries])
-            dmed = np.stack([d for _, d, _ in entries])
-            wmed = np.stack([w for _, _, w in entries])
-            cd = np.empty_like(dmed)
-            sd = np.empty_like(dmed)
-            cw = np.empty_like(wmed)
-            sw = np.empty_like(wmed)
-            for b, (i, _, _) in enumerate(entries):
-                cd[b], sd[b] = _mixed_center_scale(
-                    dmed[b], valid[b], gkey[b], n, None, "delay")
-                cw[b], sw[b] = _mixed_center_scale(
-                    wmed[b], valid[b], gkey[b], n, None, "wait")
-            fold = fold_fn(gkey, valid, dmed, wmed, cd, sd, cw, sw,
-                           cfg.mad_threshold, cfg.row_col_fraction,
-                           cfg.min_observations)
-            fold = {k: np.asarray(v) for k, v in fold.items()}
-            for b, (i, _, _) in enumerate(entries):
-                results[i] = _fold_verdict_list(
-                    {k: v[b] for k, v in fold.items()}, gkey[b], n)
+            count("c4d.fold_windows", len(entries))
+            with span("c4d.center_scale", windows=len(entries)):
+                gkey = np.stack([packs[i].layout.gkey for i, _, _ in entries])
+                valid = np.stack([packs[i].layout.gvalid
+                                  for i, _, _ in entries])
+                dmed = np.stack([d for _, d, _ in entries])
+                wmed = np.stack([w for _, _, w in entries])
+                cd = np.empty_like(dmed)
+                sd = np.empty_like(dmed)
+                cw = np.empty_like(wmed)
+                sw = np.empty_like(wmed)
+                for b, (i, _, _) in enumerate(entries):
+                    cd[b], sd[b] = _mixed_center_scale(
+                        dmed[b], valid[b], gkey[b], n, None, "delay")
+                    cw[b], sw[b] = _mixed_center_scale(
+                        wmed[b], valid[b], gkey[b], n, None, "wait")
+            with span("c4d.fold", windows=len(entries)):
+                fold = fold_fn(gkey, valid, dmed, wmed, cd, sd, cw, sw,
+                               cfg.mad_threshold, cfg.row_col_fraction,
+                               cfg.min_observations)
+                fold = {k: np.asarray(v) for k, v in fold.items()}
+                for b, (i, _, _) in enumerate(entries):
+                    results[i] = _fold_verdict_list(
+                        {k: v[b] for k, v in fold.items()}, gkey[b], n)
     return results        # type: ignore[return-value]
 
 

@@ -449,30 +449,17 @@ def batched_fused_window_kernel(n: int, n_pad: int):
 _FACTORIES = (batched_pair_median_kernel, batched_slow_fold_kernel,
               batched_hang_kernel, batched_fused_window_kernel)
 
-_JITTED = {"fused_window_kernel": fused_window_kernel,
-           "pair_median_kernel": pair_median_kernel,
-           "slow_fold_kernel": slow_fold_kernel,
-           "hang_kernel": hang_kernel,
-           "grouped_median_kernel": grouped_median_kernel,
-           "ewma_scan_kernel": ewma_scan_kernel,
-           "waterfill_kernel": waterfill_kernel}
-
 
 def cache_info() -> dict:
-    """Kernel-cache occupancy: the bounded vmap-factory LRUs plus each jit
-    kernel's traced-computation count.  Surfaced by ``jaxsim.cache_info()``
-    and stamped into ``benchmarks.run --json`` artifacts so a fleet-scale
-    run can prove pad-bucket growth stayed bounded."""
+    """Kernel-cache occupancy: the bounded vmap-factory LRUs.  Surfaced by
+    ``jaxsim.cache_info()`` and stamped into ``benchmarks.run --json``
+    artifacts so a fleet-scale run can prove pad-bucket growth stayed
+    bounded."""
     factories = {}
     for fn in _FACTORIES:
         ci = fn.cache_info()
         factories[fn.__name__] = {
             "hits": ci.hits, "misses": ci.misses,
             "size": ci.currsize, "maxsize": ci.maxsize}
-    jit_entries = {}
-    for name, fn in _JITTED.items():
-        size_fn = getattr(fn, "_cache_size", None)
-        jit_entries[name] = int(size_fn()) if callable(size_fn) else None
     return {"factory_maxsize": FACTORY_CACHE_SIZE,
-            "factories": factories,
-            "jit_entries": jit_entries}
+            "factories": factories}

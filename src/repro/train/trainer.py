@@ -31,6 +31,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.common import jax_compat as jc
 from repro.common.config import RunConfig, ShapeSpec
+from repro.common.tracing import span, step_span
 from repro.core.c4d.master import C4DMaster
 from repro.core.cluster import SimCluster, SteeringService
 from repro.core.faults import Fault, RingJobTelemetry
@@ -147,9 +148,10 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def _save_checkpoint(self, blocking: bool = False):
-        tree = {"params": self.params, "opt": self.opt_state,
-                "step": np.asarray(self.step)}
-        self.ckpt.save(self.step, tree, blocking=blocking)
+        with span("train.checkpoint", step=self.step):
+            tree = {"params": self.params, "opt": self.opt_state,
+                    "step": np.asarray(self.step)}
+            self.ckpt.save(self.step, tree, blocking=blocking)
 
     def _restore_checkpoint(self):
         template = {"params": self.params, "opt": self.opt_state,
@@ -221,21 +223,28 @@ class Trainer:
             if fault is not None:
                 # remove from schedule so the retried step does not re-fault
                 injector.schedule.pop(self.step, None)
-                self._handle_fault(fault, self.step)
+                with span("train.fault", step=self.step, kind=fault.kind):
+                    self._handle_fault(fault, self.step)
                 continue
-            batch = {k: jnp.asarray(v) for k, v in
-                     self.pipeline.batch(self.step).items()}
-            self.monitor.start()
-            with jc.set_mesh(self.mesh):
-                self.params, self.opt_state, metrics = self._step_fn(
-                    self.params, self.opt_state, batch)
-                loss = float(metrics["loss"])
-            self.monitor.stop(self.step)
-            self.report.losses.append(loss)
-            self.report.grad_norms.append(float(metrics["grad_norm"]))
-            self.report.steps_run += 1
-            self.step += 1
-            if self.step % run.train.checkpoint_every == 0:
-                self._save_checkpoint()
+            with step_span("train.step", self.step):
+                with span("train.batch"):
+                    batch = {k: jnp.asarray(v) for k, v in
+                             self.pipeline.batch(self.step).items()}
+                self.monitor.start()
+                with jc.set_mesh(self.mesh):
+                    with span("train.dispatch"):
+                        self.params, self.opt_state, metrics = self._step_fn(
+                            self.params, self.opt_state, batch)
+                    # the host waits here for the step: the BSP boundary
+                    # the StepMonitor anchors on
+                    with span("train.loss_sync"):
+                        loss = float(metrics["loss"])
+                self.monitor.stop(self.step)
+                self.report.losses.append(loss)
+                self.report.grad_norms.append(float(metrics["grad_norm"]))
+                self.report.steps_run += 1
+                self.step += 1
+                if self.step % run.train.checkpoint_every == 0:
+                    self._save_checkpoint()
         self.ckpt.wait()
         return self.report

@@ -20,6 +20,7 @@ import json
 import numpy as np
 import pytest
 
+from repro.common.tracing import counters
 from repro.core.c4d.detector import C4DDetector, DetectorConfig
 from repro.core.c4d.master import C4DMaster, OperatingPoint
 from repro.core.c4d.telemetry import delay_matrix, grouped_median, wait_matrix
@@ -107,9 +108,12 @@ def test_cache_info_shape():
     for stats in info["factories"].values():
         assert stats["maxsize"] == info["factory_maxsize"]
         assert stats["size"] <= stats["maxsize"]
-    assert "fused_window_kernel" in info["jit_entries"]
     lay = info["window_layouts"]
     assert lay["entries"] <= lay["max_entries"]
+    # the layout cache's hits and misses are the process's counters
+    tally = counters()
+    assert (lay["hits"], lay["misses"]) == (
+        tally.get("c4d.layout_hits", 0), tally.get("c4d.layout_misses", 0))
 
 
 # ---------------------------------------------------------------------------

@@ -20,10 +20,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.common.tracing import span
+from repro.common.tracing import count, span
 from repro.core.c4d.telemetry import (Heartbeat, TelemetryArrays,
-                                      TelemetryWindow, TransportRecord,
-                                      grouped_median)
+                                      TelemetryWindow, TransportRecord)
 
 
 @dataclass
@@ -105,6 +104,53 @@ def reports_to_window(reports: Sequence[AgentReport],
     return win
 
 
+#: the padded ``(groups, width)`` matrix of ``_KeyGroups`` is used while it
+#: holds at most this many slots per record; past it (a few groups far
+#: larger than the rest) one lexsort by (group, value) takes its place
+_ROW_SORT_SLOTS_PER_RECORD = 4
+
+
+class _KeyGroups:
+    """Groups of a key-ordered record array, as contiguous runs.
+
+    ``median(values)`` takes one value per record, in key order, and
+    returns each group's median, bit-identical to ``grouped_median``: it
+    reads the same two order statistics of the same multiset and averages
+    them as ``0.5 * (lo + hi)``.  Regular groups are scattered into a
+    NaN-padded ``(groups, width)`` matrix sorted along its rows (NaN sorts
+    last there, as in ``lexsort``, so a NaN value reads the same);
+    skewed ones fall back to one lexsort by (group, value)."""
+
+    def __init__(self, sorted_keys: np.ndarray):
+        t = sorted_keys.size
+        self.starts = np.flatnonzero(
+            np.r_[True, sorted_keys[1:] != sorted_keys[:-1]])
+        self.counts = np.diff(np.r_[self.starts, t])
+        g = self.starts.size
+        self.width = int(self.counts.max())
+        if g * self.width <= _ROW_SORT_SLOTS_PER_RECORD * t:
+            first = np.arange(g, dtype=np.int64) * self.width
+            self.slot = np.arange(t, dtype=np.int64) + np.repeat(
+                first - self.starts, self.counts)
+        else:
+            first = self.starts
+            self.slot = None
+            self.keys = sorted_keys
+        self.lo = first + (self.counts - 1) // 2
+        self.hi = first + self.counts // 2
+
+    def median(self, values: np.ndarray) -> np.ndarray:
+        if self.slot is not None:
+            count("c4d.prefilter.row_sorts")
+            mat = np.full(self.starts.size * self.width, np.nan)
+            mat[self.slot] = values
+            mat.reshape(-1, self.width).sort(axis=1)
+        else:
+            count("c4d.prefilter.lexsort_fallbacks")
+            mat = values[np.lexsort((values, self.keys))]
+        return 0.5 * (mat[self.lo] + mat[self.hi])
+
+
 def prefilter_arrays(window: TelemetryArrays, ranks_per_node: int,
                      suspect_z: float = 3.0,
                      n_ranks: Optional[int] = None) -> TelemetryArrays:
@@ -112,43 +158,48 @@ def prefilter_arrays(window: TelemetryArrays, ranks_per_node: int,
 
     One pass over the struct-of-arrays window:
 
-      1. per-node robust statistics (median / MAD of the node's transfer
+      1. one stable sort of the edge key ``src * n + dst`` groups the
+         records by edge, and so by node (``src // ranks_per_node``), whose
+         records form contiguous runs of the same order,
+      2. per-node robust statistics (median / MAD of the node's transfer
          latencies) flag raw suspects above ``suspect_z``,
-      2. per-edge grouped medians become the representative summary records
+      3. per-edge grouped medians become the representative summary records
          (``t_start = median wait``, ``t_end = median wait + median
          transfer``, bytes = total // count — the exact reassembly
          arithmetic of ``reports_to_window``),
-      3. heartbeats pass through untouched.
+      4. heartbeats pass through untouched.
 
     Returns the merged master-side window; downstream detection on it is
     verdict-identical to the scalar agent path.
     """
     n = n_ranks or window.n_ranks()
-    transfer = window.tr_transfer()
-    wait = window.tr_wait()
-    node = window.tr_src // ranks_per_node
 
-    if transfer.size:
+    if window.tr_src.size:
+        with span("c4d.prefilter.groups"):
+            key = window.tr_src * n + window.tr_dst
+            order = np.argsort(key, kind="stable")
+            sk = key[order]
+            edges = _KeyGroups(sk)
+            nodes = _KeyGroups(sk // n // ranks_per_node)
+            transfer = window.tr_transfer()[order]
+
         with span("c4d.prefilter.node_stats"):
             # per-node median / MAD, mapped back onto each record
-            _, node_med, _, idx = grouped_median(node, transfer,
-                                                 return_groups=True)
-            absdev = np.abs(transfer - node_med[idx])
-            _, node_mad = grouped_median(node, absdev)
-            mad = node_mad * 1.4826 + 1e-12
-            suspect = (transfer - node_med[idx]) / mad[idx] > suspect_z
+            node_med = np.repeat(nodes.median(transfer), nodes.counts)
+            mad = nodes.median(np.abs(transfer - node_med)) * 1.4826 + 1e-12
+            suspect = np.empty(order.size, bool)
+            suspect[order] = ((transfer - node_med)
+                              / np.repeat(mad, nodes.counts) > suspect_z)
 
         with span("c4d.prefilter.edge_medians"):
-            key = window.tr_src * n + window.tr_dst
-            uk, med_t, counts, edge_of = grouped_median(key, transfer,
-                                                        return_groups=True)
-            _, med_w = grouped_median(key, wait)
-            byte_sum = np.zeros(uk.size, np.int64)
-            np.add.at(byte_sum, edge_of, window.tr_bytes)
+            uk = sk[edges.starts]
+            med_t = edges.median(transfer)
+            med_w = edges.median(window.tr_wait()[order])
+            byte_sum = np.add.reduceat(window.tr_bytes[order], edges.starts)
 
         m_src = np.r_[uk // n, window.tr_src[suspect]]
         m_dst = np.r_[uk % n, window.tr_dst[suspect]]
-        m_bytes = np.r_[byte_sum // np.maximum(counts, 1),
+        m_bytes = np.r_[byte_sum // edges.counts,
                         window.tr_bytes[suspect]]
         m_post = np.r_[np.zeros(uk.size), window.tr_post[suspect]]
         m_start = np.r_[med_w, window.tr_start[suspect]]

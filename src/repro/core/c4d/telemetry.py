@@ -252,7 +252,6 @@ AnyWindow = Union[TelemetryWindow, TelemetryArrays]
 
 
 def grouped_median(keys: np.ndarray, values: np.ndarray,
-                   return_groups: bool = False,
                    backend: Optional[str] = None) -> Tuple[np.ndarray, ...]:
     """Median of ``values`` per distinct key, vectorized.
 
@@ -261,19 +260,12 @@ def grouped_median(keys: np.ndarray, values: np.ndarray,
     ``np.median`` per group: both reduce the same multiset, and the
     even-count mean ``0.5 * (a + b)`` equals NumPy's ``(a + b) / 2``.
 
-    With ``return_groups`` also returns (counts per group, inverse index
-    mapping each input element to its group), so callers that need
-    per-group sums or element->group lookups reuse this sort instead of
-    re-sorting (``agent.prefilter_arrays`` on the campaign hot path).
-
     ``backend="jax"`` (or a process default of jax, see ``core.jaxsim``)
     runs the sort/fold as a jit kernel under x64 — same keys, bit-equal
-    medians.  The group-structure variant stays NumPy: its consumers are
-    host-side prefilters.
+    medians.
     """
     from repro.core.jaxsim import effective_backend
-    if (not return_groups
-            and effective_backend(backend, elements=keys.size) == "jax"):
+    if effective_backend(backend, elements=keys.size) == "jax":
         from repro.common.jax_compat import enable_x64
         from repro.core.jaxsim.kernels import (PAD_KEY,
                                                grouped_median_kernel, pad_len)
@@ -293,12 +285,7 @@ def grouped_median(keys: np.ndarray, values: np.ndarray,
     counts = np.diff(np.r_[starts, k.size])
     lo = v[starts + (counts - 1) // 2]
     hi = v[starts + counts // 2]
-    med = 0.5 * (lo + hi)
-    if not return_groups:
-        return k[starts], med
-    inverse = np.empty(k.size, np.int64)
-    inverse[order] = np.repeat(np.arange(starts.size), counts)
-    return k[starts], med, counts, inverse
+    return k[starts], 0.5 * (lo + hi)
 
 
 def _pair_matrix(arr: TelemetryArrays, values: np.ndarray, n: int,

@@ -6,9 +6,12 @@ scalar dataclass pipeline on the golden fault windows: same RNG stream,
 same matrices, same verdicts, same master actions.  Any divergence is a
 bug in the vectorized path — the scalar implementations are the spec.
 """
+from dataclasses import replace
+
 import numpy as np
 import pytest
 
+from repro.common import tracing
 from repro.core.c4d.agent import C4Agent, prefilter_arrays, reports_to_window
 from repro.core.c4d.detector import (C4DDetector, DelayMatrixDetector,
                                      DetectorConfig, HangDetector,
@@ -169,6 +172,120 @@ def test_prefilter_arrays_equivalent_matrices(faults):
                           delay_matrix(merged_vec, N), equal_nan=True)
     assert np.array_equal(wait_matrix(merged_ref, N),
                           wait_matrix(merged_vec, N), equal_nan=True)
+
+
+def _lexsort_groups(keys, values):
+    """The grouped median as one lexsort by (key, value): sorted unique
+    keys, medians, counts, and each record's group."""
+    order = np.lexsort((values, keys))
+    k, v = keys[order], values[order]
+    starts = np.flatnonzero(np.r_[True, k[1:] != k[:-1]])
+    counts = np.diff(np.r_[starts, k.size])
+    med = 0.5 * (v[starts + (counts - 1) // 2] + v[starts + counts // 2])
+    inverse = np.empty(k.size, np.int64)
+    inverse[order] = np.repeat(np.arange(starts.size), counts)
+    return k[starts], med, counts, inverse
+
+
+def _lexsort_prefilter(w, ranks_per_node, n, suspect_z=3.0):
+    """The prefilter's transport columns with four lexsort grouped medians
+    (node median, node MAD, edge transfer, edge wait): the oracle the
+    shared key sort of ``prefilter_arrays`` is pinned to."""
+    transfer, wait = w.tr_transfer(), w.tr_wait()
+    node = w.tr_src // ranks_per_node
+    _, node_med, _, idx = _lexsort_groups(node, transfer)
+    _, node_mad, _, _ = _lexsort_groups(node,
+                                        np.abs(transfer - node_med[idx]))
+    mad = node_mad * 1.4826 + 1e-12
+    sus = (transfer - node_med[idx]) / mad[idx] > suspect_z
+    uk, med_t, counts, edge_of = _lexsort_groups(w.tr_src * n + w.tr_dst,
+                                                 transfer)
+    _, med_w, _, _ = _lexsort_groups(w.tr_src * n + w.tr_dst, wait)
+    byte_sum = np.zeros(uk.size, np.int64)
+    np.add.at(byte_sum, edge_of, w.tr_bytes)
+    return {"tr_src": np.r_[uk // n, w.tr_src[sus]],
+            "tr_dst": np.r_[uk % n, w.tr_dst[sus]],
+            "tr_bytes": np.r_[byte_sum // counts, w.tr_bytes[sus]],
+            "tr_post": np.r_[np.zeros(uk.size), w.tr_post[sus]],
+            "tr_start": np.r_[med_w, w.tr_start[sus]],
+            "tr_end": np.r_[med_w + med_t, w.tr_end[sus]]}
+
+
+def _transports(w, keep):
+    """``w`` with the transport records ``keep`` (a mask or an index)."""
+    return replace(w, **{f: getattr(w, f)[keep]
+                         for f in ("tr_src", "tr_dst", "tr_bytes",
+                                   "tr_post", "tr_start", "tr_end")})
+
+
+def _prefilter_case(case, faults):
+    """(window, rank count) of one case of the oracle test."""
+    if case == "golden":
+        return RingJobTelemetry(n_ranks=N, seed=11).window_arrays(0, faults), N
+    rng = np.random.default_rng(17)
+    w = RingJobTelemetry(n_ranks=N, seed=13).window_arrays(0)
+    if case == "dropped":
+        # each edge keeps its records with a probability of its own
+        edge = w.tr_src * N + w.tr_dst
+        w = _transports(w, rng.random(edge.size)
+                        < rng.random(N * N)[edge])
+        counts = np.unique(w.tr_src * N + w.tr_dst, return_counts=True)[1]
+        assert {1, 10} <= set(counts)
+        return w, N
+    if case == "nan_wait":
+        # one edge left with two records, one of them with no post time:
+        # its median wait is NaN
+        edge = w.tr_src * N + w.tr_dst
+        mine = np.flatnonzero(edge == edge[0])
+        w = _transports(w, np.setdiff1d(np.arange(edge.size), mine[2:]))
+        w.tr_post[0] = np.nan
+        return w, N
+    # skewed: node 0 of eight carries ~50x the records of each other node
+    n = 64
+    w = RingJobTelemetry(n_ranks=n, seed=13).window_arrays(0)
+    mine = np.flatnonzero(w.tr_src // 8 == 0)
+    extra = _transports(w, np.tile(mine, 49))
+    extra.tr_end = extra.tr_end + rng.uniform(0, 1e-3, extra.tr_end.size)
+    w = replace(w, **{f: np.r_[getattr(w, f), getattr(extra, f)]
+                      for f in ("tr_src", "tr_dst", "tr_bytes", "tr_post",
+                                "tr_start", "tr_end")})
+    return w, n
+
+
+#: (case, faults, grouped medians on the padded row sort, lexsort fallbacks)
+PREFILTER_CASES = (
+    [pytest.param("golden", f, 4, 0, id=f"golden{i}")
+     for i, f in enumerate(GOLDEN_FAULTS)]
+    + [pytest.param("dropped", [], 4, 0, id="dropped"),
+       pytest.param("nan_wait", [], 4, 0, id="nan_wait"),
+       pytest.param("skewed", [], 0, 4, id="skewed")])
+
+
+@pytest.mark.parametrize("case, faults, row_sorts, fallbacks",
+                         PREFILTER_CASES)
+def test_prefilter_arrays_bit_identical_to_lexsort_oracle(case, faults,
+                                                          row_sorts,
+                                                          fallbacks):
+    """The shared key sort and per-group row sorts return the merged
+    window of four lexsort grouped medians, bit for bit and in order."""
+    w, n = _prefilter_case(case, faults)
+    want = _lexsort_prefilter(w, 8, n)
+    before = tracing.counters()
+    got = prefilter_arrays(w, 8, n_ranks=n)
+    after = tracing.counters()
+    for f, x in want.items():
+        y = getattr(got, f)
+        assert (y.dtype, y.shape) == (x.dtype, x.shape), f
+        assert y.tobytes() == x.tobytes(), f
+    assert got.hb_rank is w.hb_rank and got.hb_t is w.hb_t
+    assert got.train is w.train
+    if case == "nan_wait":
+        assert np.isnan(got.tr_start).sum() == 1
+    moved = {k: after.get(k, 0) - before.get(k, 0)
+             for k in ("c4d.prefilter.row_sorts",
+                       "c4d.prefilter.lexsort_fallbacks")}
+    assert moved == {"c4d.prefilter.row_sorts": row_sorts,
+                     "c4d.prefilter.lexsort_fallbacks": fallbacks}
 
 
 @pytest.mark.parametrize("faults", GOLDEN_FAULTS)

@@ -27,6 +27,7 @@ N = 32
 SPAN_TREE = {
     "c4d.ingest": None,
     "c4d.prefilter": "c4d.ingest",
+    "c4d.prefilter.groups": "c4d.prefilter",
     "c4d.prefilter.node_stats": "c4d.prefilter",
     "c4d.prefilter.edge_medians": "c4d.prefilter",
     "c4d.detect": "c4d.ingest",
@@ -46,7 +47,8 @@ SPAN_TREE = {
 }
 COUNTERS = {"c4d.windows", "c4d.transports_in", "c4d.transports_kept",
             "c4d.layout_hits", "c4d.layout_misses", "c4d.hang_windows",
-            "c4d.fold_windows", "c4d.node_actions"}
+            "c4d.fold_windows", "c4d.node_actions",
+            "c4d.prefilter.row_sorts", "c4d.prefilter.lexsort_fallbacks"}
 
 FAULT_FREE = []
 HANG = [Fault("comm_hang", rank=11)]
@@ -144,6 +146,8 @@ def test_c4d_counters_move_by_what_the_master_did(batched):
     assert moved.pop("c4d.transports_in") == sum(int(w.tr_src.size)
                                                  for w in windows)
     assert moved.pop("c4d.transports_kept") == kept
+    # four grouped medians a window, all on the padded row sort
+    assert moved.pop("c4d.prefilter.row_sorts") == 4 * len(windows)
     # both windows reach the layout: each is a hit or a miss
     assert (moved.pop("c4d.layout_hits", 0)
             + moved.pop("c4d.layout_misses", 0)) == 2

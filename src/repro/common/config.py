@@ -24,7 +24,7 @@ BLOCK_MLSTM = "mlstm"          # xLSTM matrix-memory block
 
 @dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int = 0
+    num_experts: int = 0          # routed experts the router scores
     top_k: int = 2
     d_ff_expert: int = 0          # per-expert hidden size
     num_shared_experts: int = 0   # deepseek-style always-on experts
@@ -32,6 +32,31 @@ class MoEConfig:
     capacity_factor: float = 1.25
     router_z_loss: float = 1e-3
     load_balance_loss: float = 1e-2
+    norm_topk_prob: bool = True   # renormalise the top-k gates to sum to 1
+    seq_aux: bool = False         # balance loss per sequence (DeepSeek-V2)
+    # --- held experts (expert parallelism; models/moe.py apply_held_moe) ---
+    # A layer with experts_held > 0 holds experts
+    # [expert_start, expert_start + experts_held) of num_experts and keeps,
+    # per forward call, at most ceil(capacity_factor * tokens * top_k
+    # * experts_held / num_experts) of the (token, expert) rows routed to
+    # them: rows of protected sequences first, then by gate affinity
+    # (DeepSeek-V2's device-level token dropping).  0 = the per-expert
+    # capacity layer over all num_experts.
+    experts_held: int = 0
+    expert_start: int = 0
+    protected_every: int = 0      # rows r of the global batch with r % n == 0
+                                  # are protected (0 = none)
+
+
+@dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling as DeepSeek-V2's ``rope_scaling`` states it."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -42,6 +67,7 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+    yarn: Optional[YarnConfig] = None   # None = plain rope
 
 
 @dataclass(frozen=True)

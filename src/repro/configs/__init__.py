@@ -19,6 +19,7 @@ ARCHS: List[str] = [
     "xlstm-125m",
     "arctic-480b",
     "deepseek-v2-236b",
+    "deepseek-v2-lite",
     "zamba2-7b",
 ]
 

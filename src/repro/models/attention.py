@@ -12,13 +12,15 @@ here doubles as the oracle and as the CPU/dry-run lowering.
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.common.config import MLAConfig, ModelConfig
-from repro.models.layers import apply_rope, softcap, truncated_normal
+from repro.common.config import MLAConfig, ModelConfig, YarnConfig
+from repro.models.layers import apply_rope, rope_freqs, softcap, truncated_normal
 
 NEG_INF = -2.3819763e38  # matches jnp.finfo(f32) order of magnitude w/o inf arithmetic
 
@@ -221,6 +223,11 @@ def layer_window(cfg: ModelConfig, layer_idx) -> Optional[jnp.ndarray]:
 # MLA (DeepSeek-V2 multi-head latent attention)
 # ---------------------------------------------------------------------------
 
+#: query rows a chunk of MLA's training attention: its float32 scores for
+#: 8 rows x 16 heads x 4,096 keys are 1 GiB a chunk of 512
+MLA_Q_CHUNK = 512
+
+
 class MLACache(NamedTuple):
     c_kv: jnp.ndarray    # (B, S_max, kv_lora)
     k_rope: jnp.ndarray  # (B, S_max, rope_dim)
@@ -250,6 +257,53 @@ def init_mla(key, cfg: ModelConfig, dtype=jnp.float32):
     return p
 
 
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention temperature: 0.1 * mscale * ln(scale) + 1."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, y: YarnConfig) -> np.ndarray:
+    """YaRN inverse frequencies (``DeepseekV2YarnRotaryEmbedding``): the
+    plain frequencies below ``beta_fast`` rotations over the original
+    context, those divided by ``factor`` above ``beta_slow``, and a linear
+    blend between."""
+    def correction_dim(rotations):
+        return (dim * math.log(y.original_max_position_embeddings
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(correction_dim(y.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    extra = rope_freqs(dim, theta)
+    return extra / y.factor * ramp + extra * (1.0 - ramp)
+
+
+def mla_softmax_scale(m: MLAConfig) -> float:
+    """(nope + rope)^-0.5, times YaRN's ``mscale_all_dim`` temperature
+    squared where rope scaling is on."""
+    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    if m.yarn is not None and m.yarn.mscale_all_dim:
+        scale *= yarn_mscale(m.yarn.factor, m.yarn.mscale_all_dim) ** 2
+    return scale
+
+
+def _mla_rope(x, positions, cfg: ModelConfig):
+    """Rope on the rope dims as ``modeling_deepseek.py`` lays them out:
+    interleaved pairs are first gathered into halves, then rotated."""
+    m = cfg.mla
+    d = x.shape[-1]
+    x = x.reshape(x.shape[:-1] + (d // 2, 2)).swapaxes(-1, -2).reshape(x.shape)
+    if m.yarn is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    y = m.yarn
+    out = apply_rope(x, positions, cfg.rope_theta,
+                     inv_freq=yarn_inv_freq(d, cfg.rope_theta, y))
+    ratio = yarn_mscale(y.factor, y.mscale) / yarn_mscale(y.factor, y.mscale_all_dim)
+    return out if ratio == 1.0 else out * jnp.asarray(ratio, out.dtype)
+
+
 def _mla_q(p, cfg: ModelConfig, x, positions):
     from repro.models.layers import apply_rmsnorm
     m = cfg.mla
@@ -262,7 +316,7 @@ def _mla_q(p, cfg: ModelConfig, x, positions):
     else:
         q = (x @ p["w_q"].astype(x.dtype)).reshape(b, s, h, qd)
     q_nope, q_rope = q[..., : m.nope_head_dim], q[..., m.nope_head_dim:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = _mla_rope(q_rope, positions, cfg)
     return q_nope, q_rope
 
 
@@ -270,7 +324,7 @@ def _mla_ckv(p, cfg: ModelConfig, x, positions):
     from repro.models.layers import apply_rmsnorm
     c_kv = apply_rmsnorm(p["kv_norm"], x @ p["w_dkv"].astype(x.dtype), cfg.norm_eps)
     k_rope = (x @ p["w_krope"].astype(x.dtype))[:, :, None, :]  # single shared head
-    k_rope = apply_rope(k_rope, positions, cfg.rope_theta)[:, :, 0]
+    k_rope = _mla_rope(k_rope, positions, cfg)[:, :, 0]
     return c_kv, k_rope
 
 
@@ -280,14 +334,15 @@ def mla_attend(p, cfg: ModelConfig, q_nope, q_rope, c_kv, k_rope, q_offset: int,
     m = cfg.mla
     b, sk = c_kv.shape[:2]
     h = cfg.n_heads
-    k_nope = (c_kv @ p["w_uk"].astype(c_kv.dtype)).reshape(b, sk, h, m.nope_head_dim)
-    v = (c_kv @ p["w_uv"].astype(c_kv.dtype)).reshape(b, sk, h, m.v_head_dim)
-    k_rope_b = jnp.broadcast_to(k_rope[:, :, None, :], (b, sk, h, m.rope_head_dim))
-    q = jnp.concatenate([q_nope, q_rope], axis=-1)
-    k = jnp.concatenate([k_nope, k_rope_b], axis=-1)
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
-    return chunked_causal_attention(q, k, v, window=None if causal else 0,
-                                    scale=scale, q_offset=q_offset)
+    with jax.named_scope("mla.attend"):
+        k_nope = (c_kv @ p["w_uk"].astype(c_kv.dtype)).reshape(b, sk, h, m.nope_head_dim)
+        v = (c_kv @ p["w_uv"].astype(c_kv.dtype)).reshape(b, sk, h, m.v_head_dim)
+        k_rope_b = jnp.broadcast_to(k_rope[:, :, None, :], (b, sk, h, m.rope_head_dim))
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate([k_nope, k_rope_b], axis=-1)
+        return chunked_causal_attention(q, k, v, window=None if causal else 0,
+                                        scale=mla_softmax_scale(m), q_offset=q_offset,
+                                        q_chunk=MLA_Q_CHUNK)
 
 
 def mla_train(p, cfg: ModelConfig, x):
@@ -327,7 +382,7 @@ def mla_decode(p, cfg: ModelConfig, x, cache: MLACache, pos):
     # absorb W_uk into q: q_lat (B,1,H,lora)
     w_uk = p["w_uk"].astype(x.dtype).reshape(m.kv_lora_rank, h, m.nope_head_dim)
     q_lat = jnp.einsum("bqhd,lhd->bqhl", q_nope, w_uk)
-    scale = (m.nope_head_dim + m.rope_head_dim) ** -0.5
+    scale = mla_softmax_scale(m)
     scores = jnp.einsum("bqhl,bkl->bhqk", q_lat.astype(jnp.float32), c_kv.astype(jnp.float32))
     scores += jnp.einsum("bqhd,bkd->bhqk", q_rope.astype(jnp.float32), k_rope.astype(jnp.float32))
     scores *= scale

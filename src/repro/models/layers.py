@@ -42,10 +42,14 @@ def rope_freqs(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, H, D) ; positions: (..., S) broadcastable."""
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               inv_freq: Optional[np.ndarray] = None) -> jnp.ndarray:
+    """x: (..., S, H, D) ; positions: (..., S) broadcastable.  ``inv_freq``
+    (D/2,) replaces the plain ``rope_freqs(D, theta)`` (YaRN)."""
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_freqs(d, theta), dtype=jnp.float32)
+    if inv_freq is None:
+        inv_freq = rope_freqs(d, theta)
+    freqs = jnp.asarray(inv_freq, dtype=jnp.float32)
     angles = positions[..., :, None, None].astype(jnp.float32) * freqs  # (..., S, 1, D/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

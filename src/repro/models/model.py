@@ -70,7 +70,8 @@ def _chunked_ce(model: LM, params, hidden, labels, chunk: int = CE_CHUNK):
 
 
 def lm_loss(model: LM, params, batch: Dict[str, jnp.ndarray]):
-    """Next-token cross entropy (+ MoE aux). Labels default to shifted tokens."""
+    """Next-token cross entropy plus the MoE layers' aux losses, summed over
+    layers. Labels default to shifted tokens."""
     hidden, aux, _ = model.forward(params, batch, mode="train", head="none")
     if "labels" in batch:
         hidden_s, labels_s = hidden, batch["labels"]
@@ -80,7 +81,9 @@ def lm_loss(model: LM, params, batch: Dict[str, jnp.ndarray]):
     loss = _chunked_ce(model, params, hidden_s, labels_s)
     metrics = {"ce_loss": loss}
     for k, v in aux.items():
-        loss = loss + v / max(model.cfg.n_layers, 1)
+        # each layer's aux loss is added whole; counts are only reported
+        if k.endswith("_loss"):
+            loss = loss + v
         metrics[k] = v
     metrics["loss"] = loss
     return loss, metrics

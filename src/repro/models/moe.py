@@ -1,6 +1,12 @@
-"""Mixture-of-Experts FFN with sort-based capacity dispatch.
+"""Mixture-of-Experts FFN: a capacity-bounded layer over all experts, and
+a layer that holds a share of them on a fixed device budget.
 
-Design notes (TPU / GSPMD):
+``apply_moe`` picks by the configuration: ``MoEConfig.experts_held``
+> 0 selects the held-expert layer (``apply_held_moe``, DeepSeek-V2-Lite),
+otherwise the per-expert capacity layer below (arctic-480b,
+deepseek-v2-236b: the 16x16 dry-run mesh shards its experts over ``model``).
+
+Per-expert capacity layer, design notes (TPU / GSPMD):
   * Dispatch is gather/scatter based, NOT the GShard dense one-hot einsum —
     the dense dispatch einsum costs ``O(k*cf*S^2*D)`` MACs per group which can
     exceed the expert FLOPs by >100x for high-k models (deepseek k=6).
@@ -10,12 +16,17 @@ Design notes (TPU / GSPMD):
     experts-over-``model``; GSPMD materialises the EP all-to-all there.
   * Capacity-bounded with token dropping (standard); capacity factor config.
 
-Supports deepseek-style shared experts and arctic-style parallel dense
-residual FFN.
+Held-expert layer: routes over all experts, keeps a fixed budget of the
+(token, expert) rows routed to its own experts and runs one grouped matrix
+product over them (``apply_held_moe``).
+
+Both support deepseek-style shared experts; the capacity layer also
+arctic-style parallel dense residual FFN.
 """
 from __future__ import annotations
 
-from typing import Optional
+import math
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,16 +35,23 @@ from repro.common.config import ModelConfig, MoEConfig
 from repro.models.layers import act_fn, init_glu_mlp, apply_glu_mlp, truncated_normal
 
 
+#: the held-expert layer's row budget is a whole number of these
+BUDGET_TILE = 128
+
+
 def init_moe(key, cfg: ModelConfig, dtype=jnp.float32):
+    """The router scores all ``num_experts``; the expert weights are those
+    the layer holds (all of them unless ``experts_held`` is set)."""
     m: MoEConfig = cfg.moe
     d = cfg.d_model
     f = m.d_ff_expert
+    e = m.experts_held or m.num_experts
     ks = jax.random.split(key, 6)
     p = {
         "router": truncated_normal(ks[0], (d, m.num_experts), d ** -0.5, jnp.float32),
-        "wi_gate": truncated_normal(ks[1], (m.num_experts, d, f), d ** -0.5, dtype),
-        "wi_up": truncated_normal(ks[2], (m.num_experts, d, f), d ** -0.5, dtype),
-        "wo": truncated_normal(ks[3], (m.num_experts, f, d), f ** -0.5, dtype),
+        "wi_gate": truncated_normal(ks[1], (e, d, f), d ** -0.5, dtype),
+        "wi_up": truncated_normal(ks[2], (e, d, f), d ** -0.5, dtype),
+        "wo": truncated_normal(ks[3], (e, f, d), f ** -0.5, dtype),
     }
     if m.num_shared_experts:
         p["shared"] = init_glu_mlp(ks[4], d, f * m.num_shared_experts, dtype)
@@ -48,22 +66,40 @@ def _capacity(tokens_per_group: int, m: MoEConfig) -> int:
 
 
 def route_topk(router_w, x, m: MoEConfig):
-    """x: (G, S, D) -> gates (G,S,k) f32, idx (G,S,k) i32, aux losses."""
+    """x: (G, S, D) -> gates (G,S,k) f32, idx (G,S,k) i32, aux losses.
+
+    Softmax over all experts' float32 logits, then the top k; the gates are
+    renormalised only with ``norm_topk_prob``.  The balance loss is
+    Switch-style over the whole batch, or with ``seq_aux`` DeepSeek-V2's
+    per-sequence form averaged over sequences; the router z-loss is added
+    where its factor is set."""
     logits = x.astype(jnp.float32) @ router_w  # (G,S,E)
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, m.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    # Switch-style load balance loss + router z-loss
-    me = jnp.mean(probs, axis=(0, 1))                                  # (E,)
-    ce = jnp.mean(
-        jnp.sum(jax.nn.one_hot(idx, m.num_experts, dtype=jnp.float32), axis=2),
-        axis=(0, 1),
-    )                                                                  # (E,)
-    lb_loss = m.num_experts * jnp.sum(me * ce) / m.top_k
-    z_loss = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
-    aux = {"moe_lb_loss": lb_loss * m.load_balance_loss,
-           "moe_z_loss": z_loss * m.router_z_loss}
+    if m.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    counts = jnp.sum(jax.nn.one_hot(idx, m.num_experts, dtype=jnp.float32), axis=2)
+    axes = (1,) if m.seq_aux else (0, 1)
+    me = jnp.mean(probs, axis=axes)                                    # ([G,] E)
+    ce = jnp.mean(counts, axis=axes)                                   # ([G,] E)
+    lb_loss = m.num_experts * jnp.mean(jnp.sum(me * ce, axis=-1)) / m.top_k
+    aux = {"moe_lb_loss": lb_loss * m.load_balance_loss}
+    if m.router_z_loss:
+        z_loss = jnp.mean(jnp.square(jax.nn.logsumexp(logits, axis=-1)))
+        aux["moe_z_loss"] = z_loss * m.router_z_loss
     return gates, idx, aux
+
+
+def aux_zeros(m: MoEConfig) -> Dict[str, jnp.ndarray]:
+    """The aux outputs of one MoE layer, zero: losses, and for the
+    held-expert layer its kept and dropped row counts."""
+    out = {"moe_lb_loss": jnp.zeros((), jnp.float32)}
+    if m.router_z_loss:
+        out["moe_z_loss"] = jnp.zeros((), jnp.float32)
+    if m.experts_held:
+        out["moe_rows_kept"] = jnp.zeros((), jnp.int32)
+        out["moe_rows_dropped"] = jnp.zeros((), jnp.int32)
+    return out
 
 
 def _dispatch_indices(idx: jnp.ndarray, num_experts: int, capacity: int):
@@ -87,12 +123,89 @@ def _dispatch_indices(idx: jnp.ndarray, num_experts: int, capacity: int):
     return dest, valid, token, kslot, order
 
 
-def apply_moe(p, cfg: ModelConfig, x, capacity: Optional[int] = None):
+def budget_rows(tokens: int, m: MoEConfig) -> int:
+    """The held-expert layer's fixed row budget for ``tokens`` tokens:
+    ceil(capacity_factor * tokens * top_k * experts_held / num_experts),
+    rounded up to a whole ``BUDGET_TILE``."""
+    rows = math.ceil(m.capacity_factor * tokens * m.top_k * m.experts_held
+                     / m.num_experts)
+    return -(-rows // BUDGET_TILE) * BUDGET_TILE
+
+
+def apply_held_moe(p, cfg: ModelConfig, x, rows=None):
+    """The layer's share of a routed MoE FFN on a fixed row budget.
+
+    x: (B, S, D); ``rows`` (B,) is each sequence's index in the global
+    batch (its position in ``x`` by default).  Routing is over all
+    ``num_experts``; of the (token, expert) rows routed to the held experts
+    [expert_start, expert_start + held), the ``budget_rows`` with the
+    highest priority are kept: rows of protected sequences first, then the
+    higher gate affinity (one sort on affinity + 1 for protected rows).
+    The rest are dropped, and empty budget slots carry zeros, so the expert
+    work has one shape whatever the routing.  The kept rows, sorted by
+    expert, go through one grouped product per weight, and come back
+    weighted by their gates.  Returns (out, aux) with the aux losses and
+    the kept and dropped row counts."""
+    m = cfg.moe
+    b, s, d = x.shape
+    k, held = m.top_k, m.experts_held
+    t = b * s
+    budget = budget_rows(t, m)
+    with jax.named_scope("moe.route"):
+        gates, idx, aux = route_topk(p["router"], x, m)
+    with jax.named_scope("moe.dispatch"):
+        local = idx.reshape(t * k) - m.expert_start
+        mine = (local >= 0) & (local < held)
+        if rows is None:
+            rows = jnp.arange(b)
+        protected = (rows % m.protected_every == 0 if m.protected_every
+                     else jnp.zeros((b,), bool))
+        bonus = jnp.broadcast_to(protected[:, None, None], (b, s, k)).reshape(t * k)
+        flat_gates = gates.reshape(t * k)
+        priority = jnp.where(mine, flat_gates + bonus.astype(jnp.float32), -1.0)
+        if budget < t * k:
+            kept_priority, slot = jax.lax.top_k(priority, budget)
+        else:
+            slot = jnp.arange(budget) % (t * k)
+            kept_priority = jnp.where(jnp.arange(budget) < t * k, priority[slot], -1.0)
+        valid = kept_priority >= 0
+        expert = jnp.where(valid, local[slot], held)
+        expert, slot, valid = jax.lax.sort((expert, slot, valid), num_keys=1,
+                                           is_stable=True)
+        # empty slots (expert == held) join the last group: zero rows
+        sizes = jnp.bincount(expert, length=held + 1)
+        group_sizes = sizes[:held].at[held - 1].add(sizes[held])
+        token = slot // k
+        xs = jnp.where(valid[:, None], x.reshape(t, d)[token], 0).astype(x.dtype)
+        row_gate = jnp.where(valid, flat_gates[slot], 0.0)
+    with jax.named_scope("moe.experts"):
+        wi_g = p["wi_gate"].astype(x.dtype)
+        wi_u = p["wi_up"].astype(x.dtype)
+        wo = p["wo"].astype(x.dtype)
+        h = act_fn(cfg.act)(jax.lax.ragged_dot(xs, wi_g, group_sizes))
+        h = h * jax.lax.ragged_dot(xs, wi_u, group_sizes)
+        y = jax.lax.ragged_dot(h, wo, group_sizes)
+    with jax.named_scope("moe.combine"):
+        out = jnp.zeros((t, d), jnp.float32).at[token].add(
+            y.astype(jnp.float32) * row_gate[:, None])
+        out = out.astype(x.dtype).reshape(b, s, d)
+    if "shared" in p:
+        out = out + apply_glu_mlp(p["shared"], x, cfg.act)
+    kept = jnp.sum(valid, dtype=jnp.int32)
+    aux["moe_rows_kept"] = kept
+    aux["moe_rows_dropped"] = jnp.sum(mine, dtype=jnp.int32) - kept
+    return out, aux
+
+
+def apply_moe(p, cfg: ModelConfig, x, capacity: Optional[int] = None, rows=None):
     """x: (B, S, D) -> (B, S, D), aux_losses dict.
 
     Groups = batch entries (already data-sharded); routing is group-local.
+    A configuration with a device budget runs ``apply_held_moe`` instead.
     """
     m = cfg.moe
+    if m.experts_held:
+        return apply_held_moe(p, cfg, x, rows)
     b, s, d = x.shape
     if s == 1 and b > 1:
         # decode: fold the batch into one routing group (per-token groups

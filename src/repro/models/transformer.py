@@ -152,8 +152,9 @@ def init_block_cache(cfg: ModelConfig, kind: str, batch: int, max_len: int, dtyp
 
 def apply_block(p, cfg: ModelConfig, kind: str, x, *, mode: str, layer_idx,
                 cache=None, pos=None, vision_embed=None, use_kernel=True,
-                sp_attn=""):
-    """Returns (x, aux_losses, new_cache)."""
+                sp_attn="", rows=None):
+    """Returns (x, aux_losses, new_cache).  ``rows`` (B,): each sequence's
+    index in the global batch, for the MoE layer's protected rows."""
     aux: Dict[str, jnp.ndarray] = {}
     if kind in (BLOCK_DENSE, BLOCK_MOE, BLOCK_SHARED_ATTN):
         h = apply_rmsnorm(p["ln1"], x, cfg.norm_eps)
@@ -181,7 +182,7 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, *, mode: str, layer_idx,
         x = x + a
         h = apply_rmsnorm(p["ln2"], x, cfg.norm_eps)
         if kind == BLOCK_MOE:
-            ff, aux = moe_mod.apply_moe(p["moe"], cfg, h)
+            ff, aux = moe_mod.apply_moe(p["moe"], cfg, h, rows=rows)
         else:
             ff = apply_glu_mlp(p["mlp"], h, cfg.act)
         if cfg.post_block_norm:
@@ -281,7 +282,7 @@ class LM:
 
     # ---- forward ---------------------------------------------------------
     def _run_segment(self, seg: Segment, seg_params, x, *, mode, cache_seg,
-                     pos, vision_embed):
+                     pos, vision_embed, rows=None):
         cfg = self.cfg
         use_kernel = self.use_kernel
         shared_p = seg_params["shared"]
@@ -301,7 +302,7 @@ class LM:
                 x, aux, new_c = apply_block(
                     p, cfg, kind, x, mode=mode, layer_idx=layer_idx,
                     cache=c, pos=pos, vision_embed=vision_embed,
-                    use_kernel=use_kernel, sp_attn=self.sp_attn)
+                    use_kernel=use_kernel, sp_attn=self.sp_attn, rows=rows)
                 if has_cache:
                     new_caches[key] = new_c
                 for k, v in aux.items():
@@ -313,8 +314,7 @@ class LM:
                       else jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
             unit_body = jax.checkpoint(unit_body, policy=policy)
 
-        aux0 = {"moe_lb_loss": jnp.zeros((), jnp.float32),
-                "moe_z_loss": jnp.zeros((), jnp.float32)} if cfg.moe is not None else {}
+        aux0 = moe_mod.aux_zeros(cfg.moe) if cfg.moe is not None else {}
         xs = (seg_params["unit"],
               cache_seg if has_cache else {},
               jnp.arange(seg.n_units))
@@ -346,7 +346,9 @@ class LM:
 
     def forward(self, params, batch: Dict[str, jnp.ndarray], *, mode: str = "train",
                 cache=None, pos=None, head: str = "full"):
-        """batch: tokens (B,S) int32 or embeddings (B,S,D); optional vision_embed.
+        """batch: tokens (B,S) int32 or embeddings (B,S,D); optional
+        vision_embed, and optional rows (B,): each sequence's index in the
+        global batch (MoE protected rows; the position in the batch else).
 
         head: "full" -> logits for every position; "last" -> final position
         only (prefill); "none" -> post-norm hidden states (chunked losses).
@@ -366,9 +368,9 @@ class LM:
             cache_seg = cache[si] if cache is not None else None
             x, aux, new_c = self._run_segment(
                 seg, params["segments"][si], x, mode=mode, cache_seg=cache_seg,
-                pos=pos, vision_embed=vision_embed)
+                pos=pos, vision_embed=vision_embed, rows=batch.get("rows"))
             for k, v in aux.items():
-                aux_all[k] = aux_all.get(k, 0.0) + v
+                aux_all[k] = aux_all[k] + v if k in aux_all else v
             new_caches.append(new_c)
         x = apply_rmsnorm(params["final_norm"], x, cfg.norm_eps)
         if head == "none":

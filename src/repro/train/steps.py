@@ -55,6 +55,10 @@ def make_train_step(model, run: RunConfig, opt_cfg: adamw.OptimizerConfig,
     acc_dtype = jnp.dtype(pcfg.grad_accum_dtype)
 
     def accumulate(params, batch):
+        # each row's index in the global batch rides along into its
+        # microbatch (the MoE layers' protected rows)
+        batch = dict(batch, rows=jnp.arange(
+            jax.tree.leaves(batch)[0].shape[0], dtype=jnp.int32))
         if k == 1:
             return grads_of(params, batch)
         mb = _split_microbatches(batch, k)
@@ -69,7 +73,10 @@ def make_train_step(model, run: RunConfig, opt_cfg: adamw.OptimizerConfig,
         zero = jc.tree_map(lambda p: jnp.zeros(p.shape, acc_dtype), params)
         (gsum, loss_sum), metrics = _scan(body, (zero, 0.0), mb)
         grads = jc.tree_map(lambda g: g / k, gsum)   # stays in acc_dtype
-        metrics = jc.tree_map(lambda m: m[-1], metrics)
+        # counts add up over the microbatches; the rest is the last one's
+        metrics = jc.tree_map(
+            lambda m: m.sum(0) if jnp.issubdtype(m.dtype, jnp.integer) else m[-1],
+            metrics)
         return loss_sum / k, metrics, grads
 
     def compress_grads(grads, opt_state):

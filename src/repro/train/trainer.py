@@ -31,7 +31,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.common import jax_compat as jc
 from repro.common.config import RunConfig, ShapeSpec
-from repro.common.tracing import span, step_span
+from repro.common.tracing import count, span, step_span
 from repro.core.c4d.master import C4DMaster
 from repro.core.cluster import SimCluster, SteeringService
 from repro.core.faults import Fault, RingJobTelemetry
@@ -43,6 +43,11 @@ from repro.train.hooks import StepMonitor
 from repro.train.steps import make_train_step
 
 log = logging.getLogger("repro.trainer")
+
+#: step metrics the host fetches at each step's sync: the loss, the
+#: gradient norm and, from held-expert MoE layers, the step's kept and
+#: dropped expert rows
+SYNCED = ("loss", "grad_norm", "moe_rows_kept", "moe_rows_dropped")
 
 
 class SimulatedFault(RuntimeError):
@@ -139,12 +144,15 @@ class Trainer:
         # params must come back on their declared shardings: without
         # out_shardings GSPMD may commit an output leaf to a different
         # layout, and the next call rejects it against in_shardings
-        # (surfaces on any mesh bigger than 1x1).
+        # (surfaces on any mesh bigger than 1x1).  The optimizer state is
+        # donated: its successor takes its buffers, so the step does not
+        # hold two copies of the moments (8 bytes a parameter).
         return jax.jit(
             step_fn,
             in_shardings=(self.param_sharding, None,
                           shd.to_shardings(batch_specs, self.mesh)),
-            out_shardings=(self.param_sharding, None, None))
+            out_shardings=(self.param_sharding, None, None),
+            donate_argnums=(1,))
 
     # ------------------------------------------------------------------
     def _save_checkpoint(self, blocking: bool = False):
@@ -236,12 +244,16 @@ class Trainer:
                         self.params, self.opt_state, metrics = self._step_fn(
                             self.params, self.opt_state, batch)
                     # the host waits here for the step: the BSP boundary
-                    # the StepMonitor anchors on
+                    # the StepMonitor anchors on; one fetch of the scalars
                     with span("train.loss_sync"):
-                        loss = float(metrics["loss"])
+                        synced = jax.device_get(
+                            {k: metrics[k] for k in SYNCED if k in metrics})
                 self.monitor.stop(self.step)
-                self.report.losses.append(loss)
-                self.report.grad_norms.append(float(metrics["grad_norm"]))
+                self.report.losses.append(float(synced["loss"]))
+                self.report.grad_norms.append(float(synced["grad_norm"]))
+                if "moe_rows_kept" in synced:
+                    count("moe.rows_kept", int(synced["moe_rows_kept"]))
+                    count("moe.rows_dropped", int(synced["moe_rows_dropped"]))
                 self.report.steps_run += 1
                 self.step += 1
                 if self.step % run.train.checkpoint_every == 0:

@@ -81,6 +81,7 @@ def test_full_config_exact_dims(arch):
         "xlstm-125m": (12, 768, 4, 4, 0, 50_304),
         "arctic-480b": (35, 7168, 56, 8, 4864, 32_000),
         "deepseek-v2-236b": (60, 5120, 128, 128, 12288, 102_400),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102_400),
         "zamba2-7b": (81, 3584, 32, 32, 14336, 32_000),
     }[arch]
     assert (m.n_layers, m.d_model, m.n_heads, m.n_kv_heads, m.d_ff,
@@ -93,6 +94,7 @@ PUBLISHED_PARAMS = {
     "musicgen-medium": (1.5e9, 0.35), "llama-3.2-vision-11b": (9.8e9, 0.15),
     "xlstm-125m": (125e6, 0.25), "arctic-480b": (480e9, 0.05),
     "deepseek-v2-236b": (236e9, 0.05), "zamba2-7b": (7.0e9, 0.1),
+    "deepseek-v2-lite": (15.7e9, 0.05),
 }
 
 
@@ -105,7 +107,7 @@ def test_param_count_near_published(arch):
 
 
 def test_shape_grid_applicability():
-    """34 runnable cells: long_500k only for sub-quadratic/compressed archs."""
+    """37 runnable cells: long_500k only for sub-quadratic/compressed archs."""
     runnable = 0
     long_ok = set()
     for arch in ARCHS:
@@ -116,4 +118,4 @@ def test_shape_grid_applicability():
                 if sname == "long_500k":
                     long_ok.add(arch)
     assert long_ok == {"gemma2-2b", "xlstm-125m", "zamba2-7b", "deepseek-v2-236b"}
-    assert runnable == 34
+    assert runnable == 37

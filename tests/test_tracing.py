@@ -5,7 +5,8 @@
 * a small ``C4DMaster`` moves each C4D counter by exactly what it did;
 * a CPU profiler capture around a small ingest and around a Trainer step
   finds every span on the host plane, nested as documented;
-* every span and counter in ``src/`` is documented, and nothing else is.
+* every span, counter and named scope in ``src/`` is documented, and
+  nothing else is.
 """
 import re
 import subprocess
@@ -48,7 +49,11 @@ SPAN_TREE = {
 COUNTERS = {"c4d.windows", "c4d.transports_in", "c4d.transports_kept",
             "c4d.layout_hits", "c4d.layout_misses", "c4d.hang_windows",
             "c4d.fold_windows", "c4d.node_actions",
-            "c4d.prefilter.row_sorts", "c4d.prefilter.lexsort_fallbacks"}
+            "c4d.prefilter.row_sorts", "c4d.prefilter.lexsort_fallbacks",
+            "moe.rows_kept", "moe.rows_dropped"}
+#: ``jax.named_scope`` names of the device ops the benchmark selects
+SCOPES = {"moe.route", "moe.dispatch", "moe.experts", "moe.combine",
+          "mla.attend"}
 
 FAULT_FREE = []
 HANG = [Fault("comm_hang", rank=11)]
@@ -253,8 +258,10 @@ def test_every_span_and_counter_is_documented():
     src = "\n".join(p.read_text() for p in (ROOT / "src").rglob("*.py"))
     spans = set(re.findall(r"\b(?:step_)?span\(\s*\"([a-z0-9_.]+)\"", src))
     counts = set(re.findall(r"\bcount\(\s*\"([a-z0-9_.]+)\"", src))
+    scopes = set(re.findall(r"\bnamed_scope\(\s*\"([a-z0-9_.]+)\"", src))
     assert spans == set(SPAN_TREE)
     assert counts == COUNTERS
+    assert scopes == SCOPES
     doc = (ROOT / "docs" / "tracing.md").read_text()
-    documented = set(re.findall(r"`((?:c4d|train)\.[a-z0-9_.]+)`", doc))
-    assert documented == spans | counts
+    documented = set(re.findall(r"`((?:c4d|train|moe|mla)\.[a-z0-9_.]+)`", doc))
+    assert documented == spans | counts | scopes

@@ -6,7 +6,8 @@
 With no option, three phases run in this one process, each through the
 entry points a user calls:
 
-  * kernels: the three Pallas kernels (flash attention, decode attention,
+  * kernels: the Pallas kernels (forward flash attention, the splash
+    training attention with its q, k and v gradients, decode attention,
     RMSNorm), compiled for the chip, against the pure-jnp oracles of
     ``repro.kernels.ref`` at smollm-135m widths;
   * detection: two ``C4DMaster`` instances at the 10,240-rank ``fleet_day``
@@ -54,6 +55,7 @@ from repro.kernels import ref  # noqa: E402
 from repro.kernels.decode_attention import decode_attention_fwd  # noqa: E402
 from repro.kernels.flash_attention import flash_attention_fwd  # noqa: E402
 from repro.kernels.rmsnorm import rmsnorm_fwd  # noqa: E402
+from repro.kernels.splash_attention import causal_attention  # noqa: E402
 from repro.launch.mesh import make_local_mesh  # noqa: E402
 from repro.train.trainer import FaultInjector, Trainer  # noqa: E402
 
@@ -129,7 +131,21 @@ def kernels_phase(batch: int, seq: int, n_heads: int, n_kv: int,
         f"(compile included), second {again:.4f} s, max rel err {err:.3e}")
     check(bool(jnp.all(jnp.isfinite(out))), "flash attention output finite")
     check(err <= KERNEL_TOL, "flash attention matches ref.flash_attention")
-    del out, want
+    del out
+
+    # the training kernel: forward and the gradients of q, k and v
+    def grads(fn):
+        return jax.jit(jax.grad(lambda q, k, v: jnp.sum(
+            fn(q, k, v, scale=scale).astype(jnp.float32)), argnums=(0, 1, 2)))
+
+    out, first = _timed(jax.jit(lambda q, k, v: causal_attention(
+        q, k, v, scale=scale)), q, k, v)
+    got, want_g = grads(causal_attention)(q, k, v), grads(ref.flash_attention)(q, k, v)
+    err = max([_rel_err(out, want)] + [_rel_err(g, w) for g, w in zip(got, want_g)])
+    log(f"[kernels] splash causal_attention: first call {first:.3f} s, max rel "
+        f"err of the output and the q, k, v gradients {err:.3e}")
+    check(err <= KERNEL_TOL, "splash attention and its gradients match ref")
+    del out, want, got, want_g
 
     qd = q[:, :1]
     pos = jnp.asarray(seq - 100, jnp.int32)

@@ -4,7 +4,8 @@ The repo pins one jax (``jax==0.9.0``, requirements.txt / pyproject.toml)
 and targets its explicit-sharding API: ``jax.sharding.AxisType``,
 ``jax.make_mesh(..., axis_types=...)``, ``jax.set_mesh``,
 ``jax.sharding.get_abstract_mesh``, top-level ``jax.shard_map`` with
-``check_vma``, ``pltpu.CompilerParams`` and the ``jax.enable_x64`` scope.
+``check_vma``, ``pltpu.CompilerParams``, the ``jax.enable_x64`` scope and
+the splash attention kernels of ``jax.experimental.pallas.ops.tpu``.
 Callers go through this module instead of reaching into jax inline, so a
 version bump is a one-file change (docs/compat.md).
 """
@@ -42,3 +43,10 @@ def tpu_compiler_params(**kwargs):
     module never pulls in the Pallas machinery)."""
     from jax.experimental.pallas import tpu as pltpu
     return pltpu.CompilerParams(**kwargs)
+
+
+def splash_attention():
+    """The splash attention package (``make_splash_mha``, ``BlockSizes``,
+    ``CausalMask``, ``MultiHeadMask``), imported on first use."""
+    from jax.experimental.pallas.ops.tpu import splash_attention as sa
+    return sa

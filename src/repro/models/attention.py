@@ -6,12 +6,15 @@ Three execution paths per variant:
   * ``*_prefill`` — same math, additionally returns the KV cache,
   * ``*_decode``  — one new token against an existing KV cache.
 
-On TPU the query-chunked path is replaced by the Pallas flash kernel via
-``repro.kernels.ops`` (dispatch happens in ``transformer.py``); the jnp code
-here doubles as the oracle and as the CPU/dry-run lowering.
+On TPU the query-chunked path is replaced by a Pallas kernel: GQA and MLA
+attention both go through ``causal_attention`` here, which runs the kernel
+under ``shard_map`` on a mesh, and ``repro.kernels.ops.flash_attention``,
+which picks the lowering; the jnp code here doubles as the oracle and as
+the CPU/dry-run lowering.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional
 
@@ -19,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common import jax_compat as jc
 from repro.common.config import MLAConfig, ModelConfig, YarnConfig
 from repro.models.layers import apply_rope, rope_freqs, softcap, truncated_normal
 
@@ -59,17 +63,15 @@ def _softmax_attend(q, k, v, mask, logit_cap: float, scale: float):
 
 
 def chunked_causal_attention(q, k, v, *, window=0, logit_cap: float = 0.0,
-                             scale: float, q_chunk: int = 1024,
-                             q_offset: int = 0) -> jnp.ndarray:
+                             scale: float, q_chunk: int = 1024) -> jnp.ndarray:
     """Query-chunked attention; memory O(q_chunk * S) instead of O(S^2).
 
-    q: (B, S, H, D); k/v: (B, Sk, Hkv, D*). ``q_offset`` is the absolute
-    position of q[0] (for prefill continuation).
+    q: (B, S, H, D); k/v: (B, Sk, Hkv, D*).
     """
     b, s, h, d = q.shape
     sk = k.shape[1]
     if s <= q_chunk:
-        mask = causal_window_mask(q_offset + jnp.arange(s), jnp.arange(sk), window)
+        mask = causal_window_mask(jnp.arange(s), jnp.arange(sk), window)
         return _softmax_attend(q, k, v, mask, logit_cap, scale)
     n_chunks = (s + q_chunk - 1) // q_chunk
     pad = n_chunks * q_chunk - s
@@ -83,7 +85,7 @@ def chunked_causal_attention(q, k, v, *, window=0, logit_cap: float = 0.0,
         # rematted: per-chunk (B, H, qc, S) scores are recomputed in the
         # backward pass, not stored as stacked scan residuals
         i, qc = args
-        q_pos = q_offset + i * q_chunk + jnp.arange(q_chunk)
+        q_pos = i * q_chunk + jnp.arange(q_chunk)
         mask = causal_window_mask(q_pos, k_pos, window)
         out = _softmax_attend(qc, k, v, mask, logit_cap, scale)
         return carry, out
@@ -92,6 +94,50 @@ def chunked_causal_attention(q, k, v, *, window=0, logit_cap: float = 0.0,
     _, outs = _scan(body, None, (jnp.arange(n_chunks), qp))
     outs = outs.transpose(1, 0, 2, 3, 4).reshape(b, n_chunks * q_chunk, h, v.shape[-1])
     return outs[:, :s]
+
+
+def causal_attention(q, k, v, *, scale: float, train: bool, use_kernel: bool,
+                     window=None, logit_cap: float = 0.0, sp_attn: str = "",
+                     q_chunk: int = 1024):
+    """Causal self attention through ``kernels.ops.flash_attention``.
+
+    The splash kernel is a ``pallas_call``, which GSPMD cannot partition:
+    on a mesh of more than one device it runs under ``shard_map``, each
+    chip on its own rows (over pod x data, or over the whole mesh with
+    ``sp_attn="batch"``) and its own heads over ``model``. Where rows or
+    heads do not divide so, the jnp path runs, partitioned by GSPMD.
+    """
+    from repro.kernels import ops as kops
+    attend = functools.partial(kops.flash_attention, window=window,
+                               logit_cap=logit_cap, scale=scale, train=train,
+                               q_chunk=q_chunk)
+    mesh = jc.get_abstract_mesh()
+    if (mesh.empty or mesh.size == 1 or kops.attention_path(
+            q, k, v, window=window, use_kernel=use_kernel, train=train) != "splash"):
+        return attend(q, k, v, use_kernel=use_kernel)
+    spec = _kernel_spec(mesh, q.shape[0], q.shape[2], k.shape[2], sp_attn)
+    if spec is None:
+        return attend(q, k, v, use_kernel=False)
+    return jc.shard_map(functools.partial(attend, use_kernel=use_kernel),
+                        mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+                        check_vma=False)(q, k, v)
+
+
+def _kernel_spec(mesh, rows: int, heads: int, kv_heads: int, sp_attn: str):
+    """The spec of q, k, v and the output, (B, S, H, D), that gives each
+    device of ``mesh`` its own rows and heads; None where they do not
+    divide."""
+    from jax.sharding import PartitionSpec as P
+
+    from repro.parallel.sharding import rows_entry
+    row_axes = ("pod", "data", "model") if sp_attn == "batch" else ("pod", "data")
+    entry = rows_entry(mesh, rows, row_axes)
+    split = 1 if entry is None else math.prod(
+        mesh.shape[a] for a in ((entry,) if isinstance(entry, str) else entry))
+    tp = 1 if sp_attn == "batch" else mesh.shape.get("model", 1)
+    if heads % tp or kv_heads % tp or split * tp != mesh.size:
+        return None
+    return P(entry, None, "model" if tp > 1 else None, None)
 
 
 # ---------------------------------------------------------------------------
@@ -164,11 +210,10 @@ def gqa_train(p, cfg: ModelConfig, x, *, window=0, use_kernel: bool = True,
     q, k, v = _gqa_qkv(p, cfg, x, positions)
     if sp_attn:
         q, k, v = _sp_shard(q, k, v, sp_attn)
-    scale = cfg.resolved_head_dim ** -0.5
-    from repro.kernels import ops as kops
-    out = kops.flash_attention(
-        q, k, v, window=window, logit_cap=cfg.attn_logit_softcap, scale=scale,
-        use_kernel=use_kernel)
+    out = causal_attention(
+        q, k, v, window=window, logit_cap=cfg.attn_logit_softcap,
+        scale=cfg.resolved_head_dim ** -0.5, train=True, use_kernel=use_kernel,
+        sp_attn=sp_attn)
     return out.reshape(b, s, -1) @ p["wo"].astype(x.dtype)
 
 
@@ -180,11 +225,10 @@ def gqa_prefill(p, cfg: ModelConfig, x, cache: KVCache, *, window=0,
     q, k, v = _gqa_qkv(p, cfg, x, positions)
     if sp_attn:
         q, k, v = _sp_shard(q, k, v, sp_attn)
-    scale = cfg.resolved_head_dim ** -0.5
-    from repro.kernels import ops as kops
-    out = kops.flash_attention(
-        q, k, v, window=window, logit_cap=cfg.attn_logit_softcap, scale=scale,
-        use_kernel=use_kernel)
+    out = causal_attention(
+        q, k, v, window=window, logit_cap=cfg.attn_logit_softcap,
+        scale=cfg.resolved_head_dim ** -0.5, train=False, use_kernel=use_kernel,
+        sp_attn=sp_attn)
     new_cache = KVCache(
         k=jax.lax.dynamic_update_slice_in_dim(cache.k.astype(k.dtype), k, 0, axis=1),
         v=jax.lax.dynamic_update_slice_in_dim(cache.v.astype(v.dtype), v, 0, axis=1),
@@ -223,8 +267,8 @@ def layer_window(cfg: ModelConfig, layer_idx) -> Optional[jnp.ndarray]:
 # MLA (DeepSeek-V2 multi-head latent attention)
 # ---------------------------------------------------------------------------
 
-#: query rows a chunk of MLA's training attention: its float32 scores for
-#: 8 rows x 16 heads x 4,096 keys are 1 GiB a chunk of 512
+#: query rows a chunk of MLA's attention on the jnp path: its float32
+#: scores for 8 rows x 16 heads x 4,096 keys are 1 GiB a chunk of 512
 MLA_Q_CHUNK = 512
 
 
@@ -328,9 +372,11 @@ def _mla_ckv(p, cfg: ModelConfig, x, positions):
     return c_kv, k_rope
 
 
-def mla_attend(p, cfg: ModelConfig, q_nope, q_rope, c_kv, k_rope, q_offset: int,
-               causal: bool = True):
-    """Naive (non-absorbed) MLA: materialise per-head K/V from the latent."""
+def mla_attend(p, cfg: ModelConfig, q_nope, q_rope, c_kv, k_rope, *,
+               train: bool, use_kernel: bool = True):
+    """Naive (non-absorbed) MLA: materialise per-head K/V from the latent,
+    then causal attention through ``kernels.ops`` (the splash kernel on a
+    TPU, ``MLA_Q_CHUNK``-row query chunks on the jnp path)."""
     m = cfg.mla
     b, sk = c_kv.shape[:2]
     h = cfg.n_heads
@@ -340,26 +386,28 @@ def mla_attend(p, cfg: ModelConfig, q_nope, q_rope, c_kv, k_rope, q_offset: int,
         k_rope_b = jnp.broadcast_to(k_rope[:, :, None, :], (b, sk, h, m.rope_head_dim))
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
         k = jnp.concatenate([k_nope, k_rope_b], axis=-1)
-        return chunked_causal_attention(q, k, v, window=None if causal else 0,
-                                        scale=mla_softmax_scale(m), q_offset=q_offset,
-                                        q_chunk=MLA_Q_CHUNK)
+        return causal_attention(q, k, v, scale=mla_softmax_scale(m), train=train,
+                                use_kernel=use_kernel, q_chunk=MLA_Q_CHUNK)
 
 
-def mla_train(p, cfg: ModelConfig, x):
+def mla_train(p, cfg: ModelConfig, x, *, use_kernel: bool = True):
     b, s, _ = x.shape
     positions = jnp.arange(s)[None, :]
     q_nope, q_rope = _mla_q(p, cfg, x, positions)
     c_kv, k_rope = _mla_ckv(p, cfg, x, positions)
-    out = mla_attend(p, cfg, q_nope, q_rope, c_kv, k_rope, q_offset=0)
+    out = mla_attend(p, cfg, q_nope, q_rope, c_kv, k_rope, train=True,
+                     use_kernel=use_kernel)
     return out.reshape(b, s, -1) @ p["wo"].astype(x.dtype)
 
 
-def mla_prefill(p, cfg: ModelConfig, x, cache: MLACache):
+def mla_prefill(p, cfg: ModelConfig, x, cache: MLACache, *,
+                use_kernel: bool = True):
     b, s, _ = x.shape
     positions = jnp.arange(s)[None, :]
     q_nope, q_rope = _mla_q(p, cfg, x, positions)
     c_kv, k_rope = _mla_ckv(p, cfg, x, positions)
-    out = mla_attend(p, cfg, q_nope, q_rope, c_kv, k_rope, q_offset=0)
+    out = mla_attend(p, cfg, q_nope, q_rope, c_kv, k_rope, train=False,
+                     use_kernel=use_kernel)
     new_cache = MLACache(
         c_kv=jax.lax.dynamic_update_slice_in_dim(cache.c_kv.astype(c_kv.dtype), c_kv, 0, 1),
         k_rope=jax.lax.dynamic_update_slice_in_dim(cache.k_rope.astype(k_rope.dtype), k_rope, 0, 1),

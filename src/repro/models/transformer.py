@@ -161,9 +161,10 @@ def apply_block(p, cfg: ModelConfig, kind: str, x, *, mode: str, layer_idx,
         new_cache = cache
         if cfg.mla is not None:
             if mode == "train":
-                a = attn.mla_train(p["attn"], cfg, h)
+                a = attn.mla_train(p["attn"], cfg, h, use_kernel=use_kernel)
             elif mode == "prefill":
-                a, new_cache = attn.mla_prefill(p["attn"], cfg, h, cache)
+                a, new_cache = attn.mla_prefill(p["attn"], cfg, h, cache,
+                                                use_kernel=use_kernel)
             else:
                 a, new_cache = attn.mla_decode(p["attn"], cfg, h, cache, pos)
         else:

@@ -222,15 +222,22 @@ def batch_axes(mesh: Mesh) -> Tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)
 
 
+def rows_entry(mesh: Mesh, rows: int, axes: Tuple[str, ...] = ("pod", "data")):
+    """The ``PartitionSpec`` entry that splits ``rows`` over the mesh's
+    ``axes`` (pod x data by default), or None where the axes' product is 1
+    or does not divide ``rows``."""
+    axes = tuple(a for a in axes if a in mesh.axis_names)
+    total = int(np.prod([mesh.shape[a] for a in axes])) if axes else 1
+    if total == 1 or rows % total:
+        return None
+    return axes if len(axes) > 1 else axes[0]
+
+
 def batch_specs(abstract_batch, mesh: Mesh):
     """Shard the leading (global batch) dim over pod x data when divisible."""
-    dp = batch_axes(mesh)
-    total = int(np.prod([mesh.shape[a] for a in dp])) if dp else 1
-
     def visit(path, leaf):
-        if leaf.ndim >= 1 and total > 1 and leaf.shape[0] % total == 0:
-            return P(dp if len(dp) > 1 else dp[0])
-        return P()
+        entry = rows_entry(mesh, leaf.shape[0]) if leaf.ndim >= 1 else None
+        return P() if entry is None else P(entry)
     return jax.tree_util.tree_map_with_path(visit, abstract_batch)
 
 

@@ -79,7 +79,7 @@ class TrainerReport:
 class Trainer:
     def __init__(self, run: RunConfig, shape: ShapeSpec, workdir: str,
                  mesh: Optional[jax.sharding.Mesh] = None,
-                 sim_nodes: int = 4, use_kernel: bool = False,
+                 sim_nodes: int = 4, use_kernel: bool = True,
                  checkpoint_async: bool = True,
                  cluster: Optional[SimCluster] = None,
                  steering: Optional[SteeringService] = None,

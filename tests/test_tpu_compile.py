@@ -11,20 +11,28 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro.common import jax_compat as jc
 from repro.common.jax_compat import enable_x64
 from repro.core.jaxsim.kernels import (fused_window_kernel, pad_len,
                                        slow_fold_kernel)
 from repro.kernels.decode_attention import decode_attention_fwd
 from repro.kernels.flash_attention import flash_attention_fwd
 from repro.kernels.rmsnorm import rmsnorm_fwd
+from repro.kernels.splash_attention import causal_attention
 
 # smollm-135m widths (configs/smollm_135m.py) at the smoke's train shape
 B, S, H, KV, D, D_MODEL = 8, 4096, 9, 3, 64, 576
+# DeepSeek-V2-Lite's latent attention: 16 heads, keys 128 + 64 wide,
+# values 128 (its softmax scale with YaRN's temperature)
+MLA_H, MLA_D, MLA_DV, MLA_SCALE = 16, 192, 128, 0.1147
 # fleet_day: 10,240 ranks; ring channels of stride 1, 3 and 7 (5 divides
 # 10,240), ten iterations per window
 RANKS = 10_240
@@ -69,6 +77,89 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     fn = jax.jit(lambda q, k, v: flash_attention_fwd(
         q, k, v, window=None, scale=D ** -0.5))
     _assert_pallas_kernel(fn.lower(q, kv, kv).compile())
+
+
+def _attention_grad(scale):
+    """The gradient of q, k and v through the training attention kernel."""
+    def loss(q, k, v):
+        return jnp.sum(causal_attention(q, k, v, scale=scale).astype(jnp.float32))
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+
+@pytest.mark.parametrize("h,kv,d,dv,scale", [
+    (H, KV, D, D, D ** -0.5),                          # smollm-135m
+    (MLA_H, MLA_H, MLA_D, MLA_DV, MLA_SCALE),          # DeepSeek-V2-Lite
+], ids=["smollm", "mla"])
+def test_splash_attention_grad_compiles_for_v5e(one_chip, h, kv, d, dv, scale):
+    args = (_arg((B, S, h, d), jnp.bfloat16, one_chip),
+            _arg((B, S, kv, d), jnp.bfloat16, one_chip),
+            _arg((B, S, kv, dv), jnp.bfloat16, one_chip))
+    _assert_pallas_kernel(_attention_grad(scale).lower(*args).compile())
+
+
+def _model_attention_grad(monkeypatch, scale, sp_attn=""):
+    """The gradient of q, k and v through the model layer's causal
+    attention, the platform check steered as a TPU answers it."""
+    from repro.kernels import ops
+    from repro.models.attention import causal_attention as model_attention
+    monkeypatch.setattr(ops, "_kernel_path", lambda use_kernel: use_kernel)
+
+    def loss(q, k, v):
+        return jnp.sum(model_attention(q, k, v, scale=scale, train=True,
+                                       use_kernel=True, sp_attn=sp_attn
+                                       ).astype(jnp.float32))
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+
+def _mesh_grad(monkeypatch, topo, mesh_shape, sp_attn, widths, spec):
+    """Compile that gradient at batch 16 on the described 2x2 host, q, k
+    and v laid out by ``spec``."""
+    h, kv, d, dv, scale = widths
+    mesh = jax.sharding.Mesh(np.array(topo.devices).reshape(mesh_shape),
+                             ("data", "model"),
+                             axis_types=(jc.AxisType.Auto,) * 2)
+    laid = NamedSharding(mesh, spec)
+    args = (_arg((16, S, h, d), jnp.bfloat16, laid),
+            _arg((16, S, kv, d), jnp.bfloat16, laid),
+            _arg((16, S, kv, dv), jnp.bfloat16, laid))
+    with jc.set_mesh(mesh):
+        return _model_attention_grad(monkeypatch, scale, sp_attn).lower(*args).compile()
+
+
+SMOLLM_WIDTHS = (H, KV, D, D, D ** -0.5)
+MLA_WIDTHS = (MLA_H, MLA_H, MLA_D, MLA_DV, MLA_SCALE)
+
+
+def test_splash_attention_grad_on_a_data_mesh_gathers_nothing(monkeypatch, topo,
+                                                              one_chip):
+    """A ``data=4`` mesh over the 2x2 host, batch 16: each chip attends its
+    own 4 rows. Without the model layer's ``shard_map`` the compiler
+    refuses the kernel or gathers q, k and v to every chip; with it there
+    is no all-gather in the program."""
+    compiled = _mesh_grad(monkeypatch, topo, (4, 1), "", SMOLLM_WIDTHS,
+                          PartitionSpec("data"))
+    _assert_pallas_kernel(compiled)
+    assert not re.search(r"all-gather", compiled.as_text())
+
+
+@pytest.mark.parametrize("sp_attn,widths,spec,kernel", [
+    # 16 heads: rows over data, heads over model
+    ("", MLA_WIDTHS, PartitionSpec("data", None, "model"), True),
+    # rows over the whole mesh (``sp_attn="batch"``)
+    ("batch", SMOLLM_WIDTHS, PartitionSpec(("data", "model")), True),
+    # 3 KV heads do not divide model=2: the jnp path, partitioned by GSPMD
+    ("", SMOLLM_WIDTHS, PartitionSpec("data"), False),
+], ids=["mla-heads", "smollm-batch", "smollm-heads-undivided"])
+def test_attention_grad_on_a_model_mesh(monkeypatch, topo, one_chip, sp_attn,
+                                        widths, spec, kernel):
+    """A ``data=2, model=2`` mesh, batch 16: the kernel runs where each chip
+    can take its own rows and heads, with no all-gather; elsewhere the
+    jnp path runs."""
+    compiled = _mesh_grad(monkeypatch, topo, (2, 2), sp_attn, widths, spec)
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == kernel
+    if kernel:
+        assert not re.search(r"all-gather", text)
 
 
 def test_flash_attention_traced_window_compiles_for_v5e(one_chip):

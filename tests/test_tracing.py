@@ -50,10 +50,12 @@ COUNTERS = {"c4d.windows", "c4d.transports_in", "c4d.transports_kept",
             "c4d.layout_hits", "c4d.layout_misses", "c4d.hang_windows",
             "c4d.fold_windows", "c4d.node_actions",
             "c4d.prefilter.row_sorts", "c4d.prefilter.lexsort_fallbacks",
-            "moe.rows_kept", "moe.rows_dropped"}
+            "moe.rows_kept", "moe.rows_dropped",
+            "attention.kernel_layers", "attention.flash_fwd_layers",
+            "attention.chunked_layers"}
 #: ``jax.named_scope`` names of the device ops the benchmark selects
 SCOPES = {"moe.route", "moe.dispatch", "moe.experts", "moe.combine",
-          "mla.attend"}
+          "mla.attend", "attention"}
 
 FAULT_FREE = []
 HANG = [Fault("comm_hang", rank=11)]
@@ -251,6 +253,53 @@ def test_trainer_spans_on_a_capture(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# trace-time counts of the attention path
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("family", ["dense", "mla", "gemma2"])
+@pytest.mark.parametrize("path", ["chunked", "kernel"])
+def test_attention_path_counted_once_per_layer(monkeypatch, family, path):
+    """Tracing a small model's train step, its layers unrolled so that each
+    traces its own attention call, counts one call a layer: on the jnp path
+    here, and on the splash kernel with the platform check steered as a TPU
+    would answer it (tracing only; nothing is compiled). gemma2's
+    alternating window is traced, which the splash kernel cannot take and
+    the forward-only kernel cannot differentiate: it trains on the jnp
+    path on a TPU too."""
+    from dataclasses import replace
+
+    import jax
+    from repro.common.config import MLAConfig, ShapeSpec
+    from repro.configs import get_smoke_config
+    from repro.kernels import ops
+    from repro.models.model import build_model, synthetic_batch
+    from repro.optim import adamw
+    from repro.train.steps import make_train_step
+    if path == "kernel":
+        monkeypatch.setattr(ops, "_kernel_path", lambda use_kernel: use_kernel)
+    run = get_smoke_config("gemma2-2b" if family == "gemma2" else "smollm-135m")
+    model_cfg = replace(run.model, n_layers=3)
+    if family == "mla":
+        model_cfg = replace(model_cfg, mla=MLAConfig(
+            kv_lora_rank=32, q_lora_rank=0, rope_head_dim=8, nope_head_dim=16,
+            v_head_dim=16))
+    run = run.replace(model=model_cfg,
+                      parallel=replace(run.parallel, remat="none", microbatches=2),
+                      train=replace(run.train, seq_len=128, global_batch=4))
+    model = build_model(run, use_kernel=True)
+    model.unroll = True
+    opt_cfg = adamw.OptimizerConfig()
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    opt_state = jax.eval_shape(lambda p: adamw.init_state(opt_cfg, p), params)
+    batch = synthetic_batch(run.model, ShapeSpec("t", 128, 4, "train"))
+    step = make_train_step(model, run, opt_cfg)
+    before = tracing.counters()
+    jax.eval_shape(step, params, opt_state, batch)
+    taken = "kernel" if path == "kernel" and family != "gemma2" else "chunked"
+    assert _delta(before) == {f"attention.{taken}_layers": 3}
+
+
+# ---------------------------------------------------------------------------
 # the documented names are the program's names
 # ---------------------------------------------------------------------------
 
@@ -263,5 +312,6 @@ def test_every_span_and_counter_is_documented():
     assert counts == COUNTERS
     assert scopes == SCOPES
     doc = (ROOT / "docs" / "tracing.md").read_text()
-    documented = set(re.findall(r"`((?:c4d|train|moe|mla)\.[a-z0-9_.]+)`", doc))
+    documented = set(re.findall(
+        r"`((?:c4d|train|moe|mla|attention)\.[a-z0-9_.]+|attention)`", doc))
     assert documented == spans | counts | scopes

@@ -159,7 +159,6 @@ def _candidate_cells(n_ranks: int, hot: Dict[Tuple[int, int, str], float]):
 def attribute_window(verdicts: Sequence[Verdict],
                      window=None, n_ranks: Optional[int] = None,
                      cfg: Optional[AttributionConfig] = None,
-                     backend: Optional[str] = None,
                      d: Optional[np.ndarray] = None,
                      w: Optional[np.ndarray] = None) -> Attribution:
     """Attribute one window's verdicts to a ranked culprit set.
@@ -190,8 +189,8 @@ def attribute_window(verdicts: Sequence[Verdict],
         if window is None:
             return att
         n = n_ranks or window.n_ranks()
-        d = delay_matrix(window, n, backend=backend) if d is None else d
-        w = wait_matrix(window, n, backend=backend) if w is None else w
+        d = delay_matrix(window, n) if d is None else d
+        w = wait_matrix(window, n) if w is None else w
     n = n_ranks or d.shape[0]
 
     hot = _hot_cells(d, w, cfg.mad_threshold)

@@ -276,8 +276,7 @@ class C4DMaster:
             if verdicts:
                 att = attribute_window(verdicts, window=merged,
                                        n_ranks=self.n_ranks,
-                                       cfg=self.attribution,
-                                       backend=self.backend)
+                                       cfg=self.attribution)
                 self.last_attribution = att
                 self.attribution_log.append((window.window_id, att))
                 verdicts = self._filter_attributed(verdicts, att)

@@ -251,33 +251,17 @@ class TelemetryArrays:
 AnyWindow = Union[TelemetryWindow, TelemetryArrays]
 
 
-def grouped_median(keys: np.ndarray, values: np.ndarray,
-                   backend: Optional[str] = None) -> Tuple[np.ndarray, ...]:
-    """Median of ``values`` per distinct key, vectorized.
+def grouped_median(keys: np.ndarray,
+                   values: np.ndarray) -> Tuple[np.ndarray, ...]:
+    """Median of ``values`` per distinct key, vectorized, in NumPy.
 
     Sorts once by (key, value) and reads each group's middle element(s);
     returns (sorted unique keys, medians).  Bit-identical to calling
     ``np.median`` per group: both reduce the same multiset, and the
     even-count mean ``0.5 * (a + b)`` equals NumPy's ``(a + b) / 2``.
-
-    ``backend="jax"`` (or a process default of jax, see ``core.jaxsim``)
-    runs the sort/fold as a jit kernel under x64 — same keys, bit-equal
-    medians.
+    The detector's device path groups its windows itself
+    (``core.jaxsim.detectors``); this fold serves the dense matrices.
     """
-    from repro.core.jaxsim import effective_backend
-    if effective_backend(backend, elements=keys.size) == "jax":
-        from repro.common.jax_compat import enable_x64
-        from repro.core.jaxsim.kernels import (PAD_KEY,
-                                               grouped_median_kernel, pad_len)
-        tp = pad_len(keys.size)
-        pk = np.full(tp, PAD_KEY, np.int64)
-        pv = np.full(tp, np.inf)
-        pk[:keys.size] = keys
-        pv[:values.size] = values
-        with enable_x64():
-            gkey, med, _, valid = grouped_median_kernel(pk, pv)
-        ok = np.asarray(valid)
-        return np.asarray(gkey)[ok], np.asarray(med)[ok]
     order = np.lexsort((values, keys))
     k = keys[order]
     v = values[order]
@@ -288,24 +272,22 @@ def grouped_median(keys: np.ndarray, values: np.ndarray,
     return k[starts], 0.5 * (lo + hi)
 
 
-def _pair_matrix(arr: TelemetryArrays, values: np.ndarray, n: int,
-                 backend: Optional[str] = None) -> np.ndarray:
+def _pair_matrix(arr: TelemetryArrays, values: np.ndarray,
+                 n: int) -> np.ndarray:
     keys = arr.tr_src * n + arr.tr_dst
-    uk, med = grouped_median(keys, values, backend=backend)
+    uk, med = grouped_median(keys, values)
     m = np.full((n, n), np.nan)
     m[uk // n, uk % n] = med
     return m
 
 
 def delay_matrix(window: AnyWindow, n_ranks: Optional[int] = None,
-                 use_bandwidth: bool = False,
-                 backend: Optional[str] = None) -> np.ndarray:
+                 use_bandwidth: bool = False) -> np.ndarray:
     """Fold transport records into the paper's Fig. 6 matrix.
 
     D[src, dst] = median transfer latency (normalised per byte) between the
     pair; NaN where no traffic was observed.  ``TelemetryArrays`` input
-    takes the vectorized grouped-median path (``backend`` selects the
-    NumPy or jax fold — bit-equal, see ``core.jaxsim``); ``TelemetryWindow``
+    takes the vectorized grouped-median path; ``TelemetryWindow``
     input is the scalar reference the vectorized fold is pinned against."""
     n = n_ranks or window.n_ranks()
     if isinstance(window, TelemetryArrays):
@@ -314,7 +296,7 @@ def delay_matrix(window: AnyWindow, n_ranks: Optional[int] = None,
         transfer = window.tr_transfer()
         v = (window.tr_bytes / transfer if use_bandwidth
              else transfer / np.maximum(window.tr_bytes, 1))
-        return _pair_matrix(window, v, n, backend=backend)
+        return _pair_matrix(window, v, n)
     acc: Dict[Tuple[int, int], List[float]] = {}
     for t in window.transports:
         v = (t.msg_bytes / t.transfer) if use_bandwidth else t.per_byte_latency
@@ -325,14 +307,14 @@ def delay_matrix(window: AnyWindow, n_ranks: Optional[int] = None,
     return d
 
 
-def wait_matrix(window: AnyWindow, n_ranks: Optional[int] = None,
-                backend: Optional[str] = None) -> np.ndarray:
+def wait_matrix(window: AnyWindow,
+                n_ranks: Optional[int] = None) -> np.ndarray:
     """W[src, dst] = median receiver wait on the (src -> dst) edge."""
     n = n_ranks or window.n_ranks()
     if isinstance(window, TelemetryArrays):
         if window.tr_src.size == 0:
             return np.full((n, n), np.nan)
-        return _pair_matrix(window, window.tr_wait(), n, backend=backend)
+        return _pair_matrix(window, window.tr_wait(), n)
     acc: Dict[Tuple[int, int], List[float]] = {}
     for t in window.transports:
         acc.setdefault((t.src_rank, t.dst_rank), []).append(t.wait)

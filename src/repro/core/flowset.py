@@ -26,7 +26,7 @@ one-link-at-a-time reference reaches).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
@@ -142,8 +142,7 @@ class FlowSet:
         return hits == 0
 
     # ---- the engine -------------------------------------------------------
-    def max_min(self, cnp_jitter: float = 0.0, seed: int = 0,
-                backend: Optional[str] = None) -> FlowRates:
+    def max_min(self, cnp_jitter: float = 0.0, seed: int = 0) -> FlowRates:
         """Weighted progressive filling over the incidence matrix.
 
         Each round: per-link unfrozen weight via scatter-add, global
@@ -151,12 +150,7 @@ class FlowSet:
         bottleneck share freezes at ``share * weight`` and its capacity is
         returned by one more scatter-add.  Exact-tie links freeze together
         (see module docstring for why that matches the scalar reference).
-
-        ``backend="jax"`` runs the filling loop as a jit-compiled
-        ``lax.while_loop`` (``core.jaxsim.waterfill``); rates agree with
-        the NumPy loop within 1e-6, not bit-exactly, so goldens stay on
-        the NumPy default.  Jitter draws and the connection/utilisation
-        epilogue stay in NumPy either way.
+        It runs in NumPy whatever the simulator backend (``core.jaxsim``).
         """
         self._ensure_pairs()
         F, L = self.n_flows, self.n_links
@@ -173,13 +167,6 @@ class FlowSet:
         touched = np.zeros(L, dtype=bool)
         if alive_pairs.any():
             touched[pair_link[alive_pairs]] = True
-
-        from repro.core.jaxsim import effective_backend
-        if effective_backend(backend, flows=F) == "jax" and F and L:
-            from repro.core.jaxsim.waterfill import waterfill_rates
-            rate, remaining = waterfill_rates(pair_flow, pair_link, w,
-                                              alive, cap)
-            return self._finish(rate, remaining, cap, touched, alive)
 
         unfrozen = alive.copy()
         rate = np.zeros(F)
@@ -205,11 +192,6 @@ class FlowSet:
                               minlength=L)
             remaining = np.maximum(remaining - dec, 0.0)
 
-        return self._finish(rate, remaining, cap, touched, alive)
-
-    def _finish(self, rate: np.ndarray, remaining: np.ndarray,
-                cap: np.ndarray, touched: np.ndarray,
-                alive: np.ndarray) -> FlowRates:
         # slowest-QP connection aggregation: bw = min_i r_i / (w_i / sum w)
         wq = np.maximum(self.weights, 1e-12)
         wsum = np.bincount(self.conn_idx, weights=wq, minlength=self.n_conns)

@@ -1,14 +1,14 @@
-"""JAX-accelerated simulation kernels + the simulator backend switch.
+"""The C4D detection device path + the simulator backend switch.
 
-The detection and flow-simulation hot paths (grouped pair medians, the
-delay/wait/hang detectors, FlowSet max-min water-filling) exist twice:
+C4D window detection (grouped pair medians, the delay/wait/hang
+detectors) exists twice:
 
-  * the NumPy implementations in ``core/c4d`` and ``core/flowset`` — the
-    pinned references every golden test is written against;
-  * ``jit``/``vmap`` ports in this package (``kernels``, ``detectors``,
-    ``waterfill``) that run the same math as one device computation with
-    padded static shapes, unlocking 100k-rank windows and batched-over-
-    trials campaign scoring (docs/jaxsim.md).
+  * the NumPy composite in ``core/c4d`` — the pinned reference every
+    golden test is written against, and the small-window path;
+  * one device pipeline in this package (``kernels``, ``detectors``):
+    ``fused_window_kernel`` -> NumPy centre/scale -> ``slow_fold_kernel``,
+    with padded static shapes and a vmapped batch of the same two kernels
+    (docs/jaxsim.md).
 
 This module is the *switch*: it resolves which backend a call should use
 without importing jax.  That matters because several CI jobs (and any
@@ -32,8 +32,8 @@ import os
 from typing import Iterator, Optional, Tuple
 
 #: the selectable simulator backends (docs/jaxsim.md).  ``"auto"`` picks
-#: per call site by problem size: NumPy below the measured crossover, jax
-#: above (and NumPy everywhere when jax is not installed).
+#: by window size: NumPy below the measured crossover, jax above (and
+#: NumPy everywhere when jax is not installed).
 BACKENDS: Tuple[str, ...] = ("numpy", "jax", "auto")
 
 #: environment override consulted when no explicit scope is active.
@@ -82,9 +82,9 @@ def set_default_backend(name: Optional[str]) -> None:
 @contextlib.contextmanager
 def use_backend(name: Optional[str]) -> Iterator[str]:
     """Scoped default backend — how ``run_scenario`` applies
-    ``ScenarioSpec.backend`` to everything beneath it (FlowSet calls deep
-    inside C4P included) without threading an argument through every
-    layer.  ``None`` leaves the current default untouched."""
+    ``ScenarioSpec.backend`` to everything beneath it (the detector deep
+    inside the engine included) without threading an argument through
+    every layer.  ``None`` leaves the current default untouched."""
     global _default_backend
     if name is None:
         yield get_default_backend()
@@ -105,32 +105,23 @@ def resolve_backend(name: Optional[str] = None) -> str:
 # ---------------------------------------------------------------------------
 # size-based dispatch for backend="auto"
 # ---------------------------------------------------------------------------
-# Crossover thresholds measured on the dev box (docs/jaxsim.md has the
-# scaling tables behind them).  Below the threshold NumPy wins on wall
-# clock; at/above it the jit kernels win.
+# Crossover threshold measured on XLA-CPU (docs/jaxsim.md has the scaling
+# table behind it).  Below it NumPy wins on wall clock; at/above it the jit
+# kernels win.
 
 #: detector windows: NumPy wins to ~128 ranks, jax from ~256 up (the fused
 #: pipeline moved the crossover down from ~1k).
 AUTO_DETECT_RANKS = 256
 
-#: grouped-median calls keyed by element count (telemetry prefilter).
-AUTO_MEDIAN_ELEMENTS = 1 << 17
-
-#: water-filling never wins on CPU jax at feasible sizes (19 ms jit vs
-#: 2.3 ms NumPy on the fig2 topology) — effectively "always NumPy".
-AUTO_WATERFILL_FLOWS = 10 ** 9
-
 
 def effective_backend(name: Optional[str] = None, *,
-                      ranks: Optional[int] = None,
-                      elements: Optional[int] = None,
-                      flows: Optional[int] = None) -> str:
+                      ranks: Optional[int] = None) -> str:
     """Resolve ``name`` to a concrete backend (``"numpy"``/``"jax"``).
 
     Non-auto names resolve exactly like ``resolve_backend``.  ``"auto"``
-    compares whichever size hint the call site supplies against that
-    call site's measured crossover, and falls back to NumPy when jax is
-    missing — so ``backend="auto"`` is always safe to request."""
+    compares the window's ``ranks`` against ``AUTO_DETECT_RANKS``, and
+    falls back to NumPy when jax is missing or no size is given — so
+    ``backend="auto"`` is always safe to request."""
     resolved = resolve_backend(name)
     if resolved != "auto":
         return resolved
@@ -138,20 +129,15 @@ def effective_backend(name: Optional[str] = None, *,
         return "numpy"
     if ranks is not None and ranks >= AUTO_DETECT_RANKS:
         return "jax"
-    if elements is not None and elements >= AUTO_MEDIAN_ELEMENTS:
-        return "jax"
-    if flows is not None and flows >= AUTO_WATERFILL_FLOWS:
-        return "jax"
     return "numpy"
 
 
 def cache_info() -> dict:
-    """Debug snapshot of the kernel-factory and layout caches (surfaced in
-    benchmark ``--json`` output).  Import-safe without jax installed."""
+    """Debug snapshot of the kernel-factory caches (surfaced in benchmark
+    ``--json`` output).  Import-safe without jax installed."""
     if not jax_available():
         return {"available": False}
-    from repro.core.jaxsim import detectors, kernels
+    from repro.core.jaxsim import kernels
     info = kernels.cache_info()
     info["available"] = True
-    info["window_layouts"] = detectors.layout_cache_info()
     return info

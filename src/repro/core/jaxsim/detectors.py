@@ -1,6 +1,6 @@
 """Host adapters: TelemetryArrays windows -> jit kernels -> Verdict lists.
 
-``analyze_arrays`` is the jax-backend twin of ``C4DDetector.analyze`` —
+``analyze_arrays`` is the jax-backend body of ``C4DDetector.analyze`` —
 same composite semantics (hang analysis pre-empts slow analysis; the
 adaptive baseline advances only on hang-free windows), same Verdict
 objects field-for-field (tests/test_jaxsim.py pins equality on the Table-3
@@ -10,11 +10,9 @@ master ingest, campaigns, benches) runs through the same fused pipeline.
 
 The fused pipeline per window (two device dispatches total):
 
-  1. host: group the transport keys (``_layout_for`` — a radix
-     ``np.argsort`` plus run-length extents, cached across windows with
-     identical layouts, which a steady telemetry stream repeats) and
-     scatter delay/wait values into the ``(2, g_pad, m_pad)`` per-group
-     matrix;
+  1. host: group the transport keys (``_WindowLayout`` — a radix
+     ``np.argsort`` plus run-length extents) and scatter delay/wait
+     values into the ``(2, g_pad, m_pad)`` per-group matrix;
   2. device (``fused_window_kernel``): segmented pair medians (row sorts)
      + heartbeat hang scoring, one jit boundary;
   3. host: hang pre-emption, then the per-group z centers/scales
@@ -31,10 +29,6 @@ The MAD center/scale step is why the pipeline is two dispatches rather
 than one: it must run in NumPy for bit identity, and it consumes the
 medians, so a single fused boundary would put ``a*b + c`` chains back on
 the exact path.  Everything around it is fused.
-
-``analyze_arrays_reference`` keeps the PR 7 per-kernel path (global
-two-key sort + separate hang dispatch) verbatim — the equivalence suite
-pins fused == per-kernel == NumPy on the golden windows.
 """
 from __future__ import annotations
 
@@ -43,7 +37,7 @@ from typing import List, Optional, Sequence
 import numpy as np
 
 from repro.common.jax_compat import enable_x64
-from repro.common.tracing import count, counters, span
+from repro.common.tracing import count, span
 from repro.core.c4d.baseline import MEANAD_TO_SIGMA, AdaptiveBaseline
 from repro.core.c4d.detector import (COMM_HANG, COMM_SLOW_DST, COMM_SLOW_LINK,
                                      COMM_SLOW_SRC, DetectorConfig,
@@ -51,15 +45,15 @@ from repro.core.c4d.detector import (COMM_HANG, COMM_SLOW_DST, COMM_SLOW_LINK,
 from repro.core.c4d.telemetry import TelemetryArrays
 from repro.core.jaxsim.kernels import (PAD_KEY, batched_fused_window_kernel,
                                        batched_slow_fold_kernel,
-                                       fused_window_kernel, hang_kernel,
-                                       pad_len, pair_median_kernel,
+                                       fused_window_kernel, pad_len,
                                        slow_fold_kernel)
 
 import jax.numpy as jnp
 
 
 # ---------------------------------------------------------------------------
-# window layouts: host-side group structure, cached across windows
+# window layouts and padding (host side; everything lands in power-of-two
+# buckets)
 # ---------------------------------------------------------------------------
 
 class _WindowLayout:
@@ -67,16 +61,11 @@ class _WindowLayout:
 
     ``scatter`` maps each transport (original order) to its flat slot in
     the ``(g_pad, m_pad)`` per-group value matrix:
-    ``mat.reshape(-1)[scatter] = values``.  Everything here depends only
-    on the *keys*, and a steady telemetry stream emits the same key layout
-    window after window (same iteration/stride/rank structure), so the
-    whole object is cached and re-validated with one memcmp (~7 ms at 3M
-    transports vs ~130 ms to rebuild)."""
+    ``mat.reshape(-1)[scatter] = values``."""
 
-    __slots__ = ("keys", "n", "g", "g_pad", "m_pad", "scatter", "gkey",
-                 "counts", "gvalid")
+    __slots__ = ("g_pad", "m_pad", "scatter", "gkey", "counts", "gvalid")
 
-    def __init__(self, keys: np.ndarray, n: int):
+    def __init__(self, keys: np.ndarray):
         t = keys.size
         order = np.argsort(keys, kind="stable")   # radix sort on int64 keys
         sk = keys[order]
@@ -87,9 +76,6 @@ class _WindowLayout:
             starts = np.zeros(0, np.int64)
             counts = np.zeros(0, np.int64)
         g = starts.size
-        self.keys = keys.copy()
-        self.n = n
-        self.g = g
         self.g_pad = pad_len(g)
         self.m_pad = pad_len(int(counts.max()) if g else 1)
         gid = np.repeat(np.arange(g, dtype=np.int64), counts)
@@ -103,72 +89,6 @@ class _WindowLayout:
         self.counts[:g] = counts
         self.gvalid = np.zeros(self.g_pad, bool)
         self.gvalid[:g] = True
-
-
-#: most-recent-first layout cache.  Bounded two ways: entry count and total
-#: cached elements (a 100k-rank layout holds ~6M int64s, so the element
-#: budget keeps the cache to a couple of giant layouts instead of eight).
-_LAYOUT_CACHE: List[_WindowLayout] = []
-_LAYOUT_CACHE_MAX = 8
-_LAYOUT_CACHE_MAX_ELEMENTS = 16_000_000
-
-
-def _layout_for(keys: np.ndarray, n: int) -> _WindowLayout:
-    for i, lay in enumerate(_LAYOUT_CACHE):
-        if (lay.n == n and lay.keys.size == keys.size
-                and np.array_equal(lay.keys, keys)):
-            count("c4d.layout_hits")
-            if i:
-                _LAYOUT_CACHE.insert(0, _LAYOUT_CACHE.pop(i))
-            return lay
-    count("c4d.layout_misses")
-    lay = _WindowLayout(keys, n)
-    _LAYOUT_CACHE.insert(0, lay)
-    total = 0
-    for i, entry in enumerate(_LAYOUT_CACHE):
-        total += 2 * entry.keys.size
-        if i and (i >= _LAYOUT_CACHE_MAX
-                  or total > _LAYOUT_CACHE_MAX_ELEMENTS):
-            del _LAYOUT_CACHE[i:]
-            break
-    return lay
-
-
-def layout_cache_info() -> dict:
-    """Occupancy/hit-rate of the host-side layout cache (part of
-    ``jaxsim.cache_info()``); the hits and misses are the process's
-    ``c4d.layout_hits`` / ``c4d.layout_misses`` counters."""
-    tally = counters()
-    return {"entries": len(_LAYOUT_CACHE),
-            "max_entries": _LAYOUT_CACHE_MAX,
-            "elements": int(sum(2 * e.keys.size for e in _LAYOUT_CACHE)),
-            "max_elements": _LAYOUT_CACHE_MAX_ELEMENTS,
-            "hits": tally.get("c4d.layout_hits", 0),
-            "misses": tally.get("c4d.layout_misses", 0)}
-
-
-# ---------------------------------------------------------------------------
-# padding helpers (host side; everything lands in power-of-two buckets)
-# ---------------------------------------------------------------------------
-
-def pack_pairs(window: TelemetryArrays, n: int):
-    """(keys, delay values, wait values) padded to the bucket size — the
-    element-aligned packing of the PR 7 per-kernel path (kept as the
-    reference the fused pipeline is pinned against).
-
-    Keys are ``src * n + dst`` (the row-major cell id); padding slots carry
-    ``PAD_KEY``/+inf so they sort last and group into invalid slots."""
-    t = int(window.tr_src.size)
-    tp = pad_len(t)
-    keys = np.full(tp, PAD_KEY, np.int64)
-    dv = np.full(tp, np.inf)
-    wv = np.full(tp, np.inf)
-    if t:
-        keys[:t] = window.tr_src * n + window.tr_dst
-        transfer = window.tr_transfer()
-        dv[:t] = transfer / np.maximum(window.tr_bytes, 1)
-        wv[:t] = window.tr_wait()
-    return keys, dv, wv, t
 
 
 def _pad_index(values: np.ndarray, size: int) -> np.ndarray:
@@ -190,7 +110,7 @@ class _PackedWindow:
         with span("c4d.layout"):
             keys = (window.tr_src * n + window.tr_dst if t
                     else np.zeros(0, np.int64))
-            lay = _layout_for(keys, n)
+            lay = _WindowLayout(keys)
         with span("c4d.pack"):
             vmat = np.full((2, lay.g_pad, lay.m_pad), np.inf)
             if t:
@@ -253,7 +173,7 @@ def _mixed_center_scale(values: np.ndarray, valid: np.ndarray,
 
 
 # ---------------------------------------------------------------------------
-# Verdict builders (shared by the fused, batched and reference paths)
+# Verdict builders (shared by the single-window and batched paths)
 # ---------------------------------------------------------------------------
 
 def _hang_verdict_list(hung: np.ndarray, seqs: np.ndarray, med: float,
@@ -442,85 +362,6 @@ def score_windows_batched(windows: Sequence[TelemetryArrays],
                     results[i] = _fold_verdict_list(
                         {k: v[b] for k, v in fold.items()}, gkey[b], n)
     return results        # type: ignore[return-value]
-
-
-# ---------------------------------------------------------------------------
-# the PR 7 per-kernel path, kept verbatim as the fused pipeline's reference
-# ---------------------------------------------------------------------------
-
-def analyze_arrays_reference(window: TelemetryArrays, cfg: DetectorConfig,
-                             n_ranks: Optional[int] = None,
-                             baseline: Optional[AdaptiveBaseline] = None
-                             ) -> List[Verdict]:
-    """The original three-dispatch analysis (separate ``hang_kernel``,
-    global two-key-sort ``pair_median_kernel``, then the fold).  The
-    equivalence suite pins ``analyze_arrays`` == this == the NumPy
-    composite on every golden window."""
-    n = n_ranks or window.n_ranks()
-    n_pad = pad_len(n)
-    with enable_x64():
-        verdicts = _hang_verdicts(window, cfg, n, n_pad, baseline)
-        if verdicts:
-            return verdicts
-        verdicts, gkey, valid, dmed, wmed = _slow_verdicts(
-            window, cfg, n, n_pad, baseline)
-    if baseline is not None:
-        _advance_baseline(window, cfg, n, baseline, gkey, valid, dmed, wmed)
-    return verdicts
-
-
-def _hang_verdicts(window, cfg, n, n_pad, baseline):
-    h = int(window.hb_rank.size)
-    hp = pad_len(h)
-    hb_valid = np.zeros(hp, bool)
-    hb_valid[:h] = True
-    t = int(window.tr_src.size)
-    sp = pad_len(t)
-    src_valid = np.zeros(sp, bool)
-    src_valid[:t] = True
-    offsets = np.zeros(n_pad)
-    if baseline is not None and n:
-        offsets[:n] = baseline.deficit_offset(np.arange(n))
-    res = hang_kernel(
-        _pad_index(window.hb_rank, hp), _pad_index(window.hb_seq, hp),
-        hb_valid, _pad_index(window.tr_src, sp), src_valid,
-        jnp.asarray(offsets), cfg.hang_grace, n_pad=n_pad)
-    hung = np.asarray(res["hung"])
-    if not hung.any():
-        return []
-    return _hang_verdict_list(hung, np.asarray(res["seqs"]),
-                              float(res["med"]), np.asarray(res["is_src"]))
-
-
-def _compact_groups(k, dmed, wmed, rep):
-    """Compact the element-aligned kernel output to one slot per real group
-    (ascending key order, padded to the group bucket)."""
-    idx = np.flatnonzero(rep)
-    g = idx.size
-    gp = pad_len(g)
-    gkey = np.full(gp, PAD_KEY, np.int64)
-    dm = np.zeros(gp)
-    wm = np.zeros(gp)
-    valid = np.zeros(gp, bool)
-    gkey[:g] = k[idx]
-    dm[:g] = dmed[idx]
-    wm[:g] = wmed[idx]
-    valid[:g] = True
-    return gkey, dm, wm, valid
-
-
-def _slow_verdicts(window, cfg, n, n_pad, baseline):
-    keys, dv, wv, t = pack_pairs(window, n)
-    k_e, dmed_e, wmed_e, _, rep_e, _ = pair_median_kernel(keys, dv, wv)
-    gkey, dmed, wmed, valid = _compact_groups(
-        np.asarray(k_e), np.asarray(dmed_e), np.asarray(wmed_e),
-        np.asarray(rep_e))
-    cd, sd = _mixed_center_scale(dmed, valid, gkey, n, baseline, "delay")
-    cw, sw = _mixed_center_scale(wmed, valid, gkey, n, baseline, "wait")
-    res = slow_fold_kernel(gkey, valid, dmed, wmed, cd, sd, cw, sw,
-                           cfg.mad_threshold, cfg.row_col_fraction,
-                           cfg.min_observations, n=n, n_pad=n_pad)
-    return _fold_verdict_list(res, gkey, n), gkey, valid, dmed, wmed
 
 
 def _advance_baseline(window, cfg, n, baseline, gkey, valid, dmed, wmed):

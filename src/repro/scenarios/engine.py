@@ -109,9 +109,9 @@ def run_scenario(spec: ScenarioSpec) -> dict:
     fabrics (identical seed/events) and the primary report carries a
     ``variants`` section plus the A/B goodput comparison.
 
-    ``spec.backend`` scopes the kernel backend for the whole run (both A/B
-    arms), so every component that resolves the default — the flow engine's
-    water-filling, grouped medians, the detector — flips together."""
+    ``spec.backend`` scopes the detection backend for the whole run (both
+    A/B arms), so every detector that resolves the default flips
+    together."""
     with use_backend(spec.backend):
         return _run_scenario(spec)
 
